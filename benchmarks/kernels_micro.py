@@ -31,7 +31,7 @@ def run():
         flash_attention, q, k, v, block_q=64, block_k=64, interpret=True),
         "B1_S256_H8_D64")
     qd = jax.random.normal(key, (2, 8, 64), jnp.float32)
-    kp = jax.random.normal(key, (16, 16, 2, 64), jnp.float32)
+    kp = jax.random.normal(key, (16, 2, 16, 64), jnp.float32)  # (P, Hkv, PS, D)
     pt = jnp.arange(8, dtype=jnp.int32).reshape(2, 4)
     ln = jnp.asarray([60, 33], jnp.int32)
     emit("kernel/paged_attention", _bench(

@@ -9,6 +9,9 @@ a plain ``--json`` refreshes the committed baselines in place).
 worker runs one module with stdout/stderr captured, and the parent prints
 the captured output in submission order, so the CSV stays deterministic.
 A crashed worker fails the run non-zero just like an in-process exception.
+Each worker imports JAX, and a TPU belongs to one process at a time, so
+``--jobs N`` with N > 1 is refused unless ``JAX_PLATFORMS=cpu``: on a chip
+run the modules in one process.
 
 ``--policy NAME`` / ``--hw NAME`` run the figure suites under a registered
 memory-policy backend / hardware model (see repro.core.registry), e.g.
@@ -99,6 +102,11 @@ def main(argv=None) -> int:
         jobs = max(1, int(jobs_s)) if jobs_s is not None else 1
     except ValueError:
         print(f"benchmarks/run.py: --jobs needs an integer, got {jobs_s!r}",
+              file=sys.stderr)
+        raise SystemExit(2)
+    if jobs > 1 and os.environ.get("JAX_PLATFORMS") != "cpu":
+        print("benchmarks/run.py: --jobs > 1 needs JAX_PLATFORMS=cpu (each "
+              "worker imports JAX, and only one process may hold a TPU)",
               file=sys.stderr)
         raise SystemExit(2)
     if "--json" in argv:

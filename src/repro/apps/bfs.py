@@ -87,7 +87,7 @@ def _frontier_views(edges: UMBuffer, nodes: np.ndarray, deg: int,
 def run_bfs(policy_kind: str = "system", *, n_nodes: int = 1 << 16, deg: int = 8,
             page_size: int = 64 * KB, oversub_ratio: float = 0.0,
             auto_migrate: bool = True, sparse_access: bool = False,
-            hw=None, interpret: bool = True) -> AppResult:
+            hw=None, interpret: bool | None = None) -> AppResult:
     edge_bytes = n_nodes * deg * 4
     node_bytes = n_nodes * 4
     um, pol = make_um(policy_kind, page_size=page_size, hw=hw,

@@ -18,7 +18,7 @@ COEFF = 0.1
 def run_hotspot(policy_kind: str = "system", *, rows: int = 1024, cols: int = 1024,
                 iters: int = 8, page_size: int = 64 * KB,
                 oversub_ratio: float = 0.0, auto_migrate: bool = True,
-                hw=None, interpret: bool = True) -> AppResult:
+                hw=None, interpret: bool | None = None) -> AppResult:
     nbytes = rows * cols * 4
     um, pol = make_um(policy_kind, page_size=page_size, hw=hw,
                       oversub_ratio=oversub_ratio,

@@ -34,7 +34,7 @@ def _nw_rows(sim, penalty: int):
 def run_needle(policy_kind: str = "system", *, n: int = 2048, penalty: int = 1,
                page_size: int = 64 * KB, waves_per_kernel: int = 64,
                oversub_ratio: float = 0.0, auto_migrate: bool = True,
-               hw=None, interpret: bool = True) -> AppResult:
+               hw=None, interpret: bool | None = None) -> AppResult:
     nbytes = n * n * 4
     um, pol = make_um(policy_kind, page_size=page_size, hw=hw,
                       oversub_ratio=oversub_ratio,

@@ -24,7 +24,7 @@ def _dp_all_rows(data):
 def run_pathfinder(policy_kind: str = "system", *, rows: int = 4096, cols: int = 1024,
                    page_size: int = 64 * KB, rows_per_kernel: int = 512,
                    oversub_ratio: float = 0.0, auto_migrate: bool = True,
-                   hw=None, interpret: bool = True) -> AppResult:
+                   hw=None, interpret: bool | None = None) -> AppResult:
     row_bytes = cols * 4
     um, pol = make_um(policy_kind, page_size=page_size, hw=hw,
                       oversub_ratio=oversub_ratio,

@@ -30,7 +30,7 @@ def run_qsim(policy_kind: str = "system", *, n_qubits: int = 16,
              depth: Optional[int] = None, page_size: int = 64 * KB,
              oversub_ratio: float = 0.0, use_prefetch: bool = False,
              auto_migrate: bool = True, seed: int = 0,
-             hw=None, interpret: bool = True) -> AppResult:
+             hw=None, interpret: bool | None = None) -> AppResult:
     depth = depth if depth is not None else max(2, n_qubits // 4)
     n_amps = 1 << n_qubits  # statevector amplitudes, 8 B each (complex64)
     um, pol = make_um(policy_kind, page_size=page_size, hw=hw,

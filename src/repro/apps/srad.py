@@ -14,7 +14,7 @@ from repro.core import Actor, KernelBatch
 from repro.kernels.stencil5 import stencil5
 
 
-def _srad_iter(J, lam: float, interpret: bool):
+def _srad_iter(J, lam: float, interpret: bool | None):
     # diffusion coefficient from local statistics, then diffusion sweep
     dN = jnp.roll(J, 1, 0) - J
     dS = jnp.roll(J, -1, 0) - J
@@ -29,7 +29,7 @@ def _srad_iter(J, lam: float, interpret: bool):
 def run_srad(policy_kind: str = "system", *, rows: int = 1024, cols: int = 1024,
              iters: int = 12, page_size: int = 64 * KB, lam: float = 0.5,
              oversub_ratio: float = 0.0, auto_migrate: bool = True,
-             threshold: int = 256, hw=None, interpret: bool = True) -> AppResult:
+             threshold: int = 256, hw=None, interpret: bool | None = None) -> AppResult:
     um, pol = make_um(policy_kind, page_size=page_size, hw=hw,
                       oversub_ratio=oversub_ratio,
                       app_peak_bytes=2 * rows * cols * 4,

@@ -2,6 +2,7 @@
 from __future__ import annotations
 
 import jax
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
@@ -11,17 +12,11 @@ def default_interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
-def tpu_compiler_params(dimension_semantics):
-    """Best-effort TPU compiler params across jax versions (None if absent)."""
-    try:
-        from jax.experimental.pallas import tpu as pltpu
-    except ImportError:  # pragma: no cover
-        return None
-    for name in ("CompilerParams", "TPUCompilerParams"):
-        cls = getattr(pltpu, name, None)
-        if cls is not None:
-            try:
-                return cls(dimension_semantics=dimension_semantics)
-            except TypeError:  # pragma: no cover
-                continue
-    return None
+def resolve_interpret(interpret: bool | None) -> bool:
+    """``None`` means "interpret only where no TPU exists"."""
+    return default_interpret() if interpret is None else interpret
+
+
+def tpu_compiler_params(dimension_semantics) -> pltpu.CompilerParams:
+    """Mosaic compiler params: the grid's parallel/arbitrary semantics."""
+    return pltpu.CompilerParams(dimension_semantics=dimension_semantics)

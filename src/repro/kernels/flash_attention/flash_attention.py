@@ -19,7 +19,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.common import NEG_INF, tpu_compiler_params
+from repro.kernels.common import NEG_INF, resolve_interpret, tpu_compiler_params
 
 
 def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
@@ -85,7 +85,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 
 def flash_attention_fwd(q, k, v, *, causal: bool = True, window: int = 0,
                         block_q: int = 128, block_k: int = 128,
-                        interpret: bool = True):
+                        interpret: bool | None = None):
     """q: (B,H,Sq,D); k,v: (B,Hkv,Sk,D). Returns (B,H,Sq,D)."""
     B, H, Sq, D = q.shape
     Hkv, Sk = k.shape[1], k.shape[2]
@@ -101,8 +101,6 @@ def flash_attention_fwd(q, k, v, *, causal: bool = True, window: int = 0,
         _kernel, scale=1.0 / math.sqrt(D), block_q=block_q, block_k=block_k,
         n_k=n_k, causal=causal, window=window, seq_k=Sk)
 
-    params = tpu_compiler_params(("parallel", "parallel", "parallel", "arbitrary"))
-    kwargs = {"compiler_params": params} if params is not None else {}
     return pl.pallas_call(
         kernel,
         grid=grid,
@@ -118,6 +116,7 @@ def flash_attention_fwd(q, k, v, *, causal: bool = True, window: int = 0,
             pltpu.VMEM((block_q, 128), jnp.float32),  # l
             pltpu.VMEM((block_q, D), jnp.float32),  # acc
         ],
-        interpret=interpret,
-        **kwargs,
+        compiler_params=tpu_compiler_params(
+            ("parallel", "parallel", "parallel", "arbitrary")),
+        interpret=resolve_interpret(interpret),
     )(q, k, v)

@@ -6,7 +6,6 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.common import default_interpret
 from repro.kernels.flash_attention.flash_attention import flash_attention_fwd
 
 
@@ -16,8 +15,6 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
                     block_q: int = 128, block_k: int = 128,
                     interpret: bool | None = None):
     """q: (B,S,H,D); k,v: (B,S,Hkv,D) -> (B,S,H,D). Layout-matches models/attention."""
-    if interpret is None:
-        interpret = default_interpret()
     qt = jnp.swapaxes(q, 1, 2)
     kt = jnp.swapaxes(k, 1, 2)
     vt = jnp.swapaxes(v, 1, 2)

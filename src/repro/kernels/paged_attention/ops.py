@@ -5,7 +5,6 @@ import functools
 
 import jax
 
-from repro.kernels.common import default_interpret
 from repro.kernels.paged_attention.paged_attention import paged_attention_fwd
 
 
@@ -14,10 +13,8 @@ def paged_attention(q, k_pool, v_pool, page_table, lengths, *,
                     interpret: bool | None = None):
     """Decode attention over a paged KV cache.
 
-    q: (B,H,D); k_pool/v_pool: (P, PS, Hkv, D); page_table: (B, NP) int32
+    q: (B,H,D); k_pool/v_pool: (P, Hkv, PS, D); page_table: (B, NP) int32
     (page ids per sequence, in order); lengths: (B,) valid tokens.
     """
-    if interpret is None:
-        interpret = default_interpret()
     return paged_attention_fwd(q, k_pool, v_pool, page_table, lengths,
                                interpret=interpret)

@@ -1,11 +1,14 @@
 """Pallas TPU paged decode attention.
 
-The KV cache lives in a page pool (P, PS, Hkv, D); each sequence owns a row
-of the page table — the serving-side materialization of the paper's system
-page table. The page table and sequence lengths ride in scalar-prefetch
-(SMEM): the k/v BlockSpec index_maps dereference the table so each grid step
-DMAs exactly one page of one kv head from HBM into VMEM. Pages past a
-sequence's length are skipped (no DMA-compute on dead pages).
+The KV cache lives in a head-major page pool (P, Hkv, PS, D); each sequence
+owns a row of the page table — the serving-side materialization of the
+paper's system page table. The page table and sequence lengths ride in
+scalar-prefetch (SMEM): the k/v BlockSpec index_maps dereference the table
+so each grid step DMAs exactly one page of one kv head from HBM into VMEM.
+Head-major keeps that block's last two dims (PS, D) whole, as Mosaic's
+(8, 128) tiling rule requires; a token-major (1, PS, 1, D) block is refused
+by the compiler. Pages past a sequence's length are skipped (no DMA-compute
+on dead pages).
 
 Grid: (B, Hkv, NP) — page dim innermost, online softmax in VMEM scratch.
 """
@@ -19,7 +22,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.common import NEG_INF, tpu_compiler_params
+from repro.kernels.common import NEG_INF, resolve_interpret, tpu_compiler_params
 
 
 def _kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
@@ -39,8 +42,8 @@ def _kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
     @pl.when(live)
     def _compute():
         q = q_ref[0, 0].astype(jnp.float32)  # (group, D)
-        k = k_ref[0, :, 0].astype(jnp.float32)  # (PS, D)
-        v = v_ref[0, :, 0].astype(jnp.float32)
+        k = k_ref[0, 0].astype(jnp.float32)  # (PS, D)
+        v = v_ref[0, 0].astype(jnp.float32)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
         s = s * (1.0 / math.sqrt(q.shape[-1]))
@@ -66,10 +69,10 @@ def _kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
 
 
 def paged_attention_fwd(q, k_pool, v_pool, page_table, lengths, *,
-                        interpret: bool = True):
-    """q: (B,H,D); pools: (P,PS,Hkv,D); page_table: (B,NP); lengths: (B,)."""
+                        interpret: bool | None = None):
+    """q: (B,H,D); pools: (P,Hkv,PS,D); page_table: (B,NP); lengths: (B,)."""
     B, H, D = q.shape
-    P, PS, Hkv, _ = k_pool.shape
+    P, Hkv, PS, _ = k_pool.shape
     NP = page_table.shape[1]
     assert H % Hkv == 0
     group = H // Hkv
@@ -84,8 +87,8 @@ def paged_attention_fwd(q, k_pool, v_pool, page_table, lengths, *,
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, 1, group, D), lambda b, h, j, pt, ln: (b, h, 0, 0)),
-            pl.BlockSpec((1, PS, 1, D), lambda b, h, j, pt, ln: (pt[b, j], 0, h, 0)),
-            pl.BlockSpec((1, PS, 1, D), lambda b, h, j, pt, ln: (pt[b, j], 0, h, 0)),
+            pl.BlockSpec((1, 1, PS, D), lambda b, h, j, pt, ln: (pt[b, j], h, 0, 0)),
+            pl.BlockSpec((1, 1, PS, D), lambda b, h, j, pt, ln: (pt[b, j], h, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, group, D), lambda b, h, j, pt, ln: (b, h, 0, 0)),
         scratch_shapes=[
@@ -94,13 +97,11 @@ def paged_attention_fwd(q, k_pool, v_pool, page_table, lengths, *,
             pltpu.VMEM((group, D), jnp.float32),
         ],
     )
-    params = tpu_compiler_params(("parallel", "parallel", "arbitrary"))
-    kwargs = {"compiler_params": params} if params is not None else {}
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, group, D), q.dtype),
-        interpret=interpret,
-        **kwargs,
+        compiler_params=tpu_compiler_params(("parallel", "parallel", "arbitrary")),
+        interpret=resolve_interpret(interpret),
     )(page_table, lengths, q4, k_pool, v_pool)
     return out.reshape(B, H, D)

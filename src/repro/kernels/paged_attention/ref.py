@@ -8,25 +8,27 @@ from repro.kernels.common import NEG_INF
 
 
 def paged_attention_ref(q, k_pool, v_pool, page_table, lengths):
-    """q: (B,H,D); pools: (P, PS, Hkv, D); page_table: (B, NP) int32;
+    """q: (B,H,D); pools: (P, Hkv, PS, D); page_table: (B, NP) int32;
     lengths: (B,) tokens valid per sequence. Returns (B,H,D).
 
     Gathers each sequence's pages then runs masked decode attention (GQA
     block mapping H = Hkv * group).
     """
     B, H, D = q.shape
-    P, PS, Hkv, _ = k_pool.shape
+    P, Hkv, PS, _ = k_pool.shape
     NP = page_table.shape[1]
     group = H // Hkv
-    k = k_pool[page_table]  # (B, NP, PS, Hkv, D)
-    v = v_pool[page_table]
-    k = k.reshape(B, NP * PS, Hkv, D).astype(jnp.float32)
-    v = v.reshape(B, NP * PS, Hkv, D).astype(jnp.float32)
+
+    def seq_major(pool):  # (B, NP, Hkv, PS, D) -> (B, Hkv, NP*PS, D)
+        kv = pool[page_table].transpose(0, 2, 1, 3, 4)
+        return kv.reshape(B, Hkv, NP * PS, D).astype(jnp.float32)
+
+    k, v = seq_major(k_pool), seq_major(v_pool)
     qf = q.astype(jnp.float32).reshape(B, Hkv, group, D)
-    logits = jnp.einsum("bngd,bknd->bngk", qf, k) / jnp.sqrt(float(D))
+    logits = jnp.einsum("bngd,bnkd->bngk", qf, k) / jnp.sqrt(float(D))
     pos = jnp.arange(NP * PS)[None, :]
     ok = pos < lengths[:, None]
     logits = jnp.where(ok[:, None, None, :], logits, NEG_INF)
     probs = jax.nn.softmax(logits, axis=-1)
-    out = jnp.einsum("bngk,bknd->bngd", probs, v)
+    out = jnp.einsum("bngk,bnkd->bngd", probs, v)
     return out.reshape(B, H, D).astype(q.dtype)

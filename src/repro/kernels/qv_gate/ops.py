@@ -6,7 +6,6 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.common import default_interpret
 from repro.kernels.qv_gate.qv_gate import qv_gate_panel
 
 
@@ -14,8 +13,6 @@ from repro.kernels.qv_gate.qv_gate import qv_gate_panel
 def apply_two_qubit_gate(state, gate, q1: int, q2: int, n_qubits: int,
                          *, interpret: bool | None = None):
     """state: (2**n,) complex64; gate: (4,4) complex64. Returns new state."""
-    if interpret is None:
-        interpret = default_interpret()
     psi = state.reshape((2,) * n_qubits)
     a1, a2 = n_qubits - 1 - q1, n_qubits - 1 - q2
     psi = jnp.moveaxis(psi, (a1, a2), (0, 1)).reshape(4, -1)

@@ -17,7 +17,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.common import tpu_compiler_params
+from repro.kernels.common import resolve_interpret, tpu_compiler_params
 
 
 def _kernel(gr_ref, gi_ref, xr_ref, xi_ref, or_ref, oi_ref):
@@ -32,14 +32,13 @@ def _kernel(gr_ref, gi_ref, xr_ref, xi_ref, or_ref, oi_ref):
     oi_ref[...] = (dot(gr, xi) + dot(gi, xr)).astype(oi_ref.dtype)
 
 
-def qv_gate_panel(xr, xi, gr, gi, *, block_m: int = 2048, interpret: bool = True):
+def qv_gate_panel(xr, xi, gr, gi, *, block_m: int = 2048,
+                  interpret: bool | None = None):
     """xr/xi: (4, M) f32 real/imag amplitude panels; gr/gi: (4,4)."""
     _, M = xr.shape
     block_m = min(block_m, M)
     assert M % block_m == 0, (M, block_m)
     grid = (M // block_m,)
-    params = tpu_compiler_params(("parallel",))
-    kwargs = {"compiler_params": params} if params is not None else {}
     return pl.pallas_call(
         _kernel,
         grid=grid,
@@ -57,6 +56,6 @@ def qv_gate_panel(xr, xi, gr, gi, *, block_m: int = 2048, interpret: bool = True
             jax.ShapeDtypeStruct(xr.shape, xr.dtype),
             jax.ShapeDtypeStruct(xi.shape, xi.dtype),
         ],
-        interpret=interpret,
-        **kwargs,
+        compiler_params=tpu_compiler_params(("parallel",)),
+        interpret=resolve_interpret(interpret),
     )(gr, gi, xr, xi)

@@ -36,6 +36,7 @@ from repro.models.cache import cache_specs
 from repro.models.transformer import decode_step, forward, init_params_specs, prefill
 from repro.optim import adamw_update
 from repro.optim.schedule import warmup_cosine
+from repro.train.trainer import split_microbatches
 
 
 def _named(mesh, spec_tree):
@@ -153,8 +154,7 @@ def make_artifacts(cfg: ArchConfig, shape: RunShape, mesh,
                 )
                 return gacc, None
 
-            mb_tree = jax.tree.map(
-                lambda x: x.reshape((accum, micro) + x.shape[1:]), batch)
+            mb_tree = split_microbatches(batch, accum)
             gacc0 = jax.tree.map(lambda s: jnp.zeros(s.shape, jnp.float32),
                                  state["params"])
             grads, _ = jax.lax.scan(one_micro, gacc0, mb_tree)

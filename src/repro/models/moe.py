@@ -116,7 +116,6 @@ def moe_apply_sorted(cfg, p, x, policy: RunPolicy, tp: int = 1
 
 def _moe_sorted_ep(cfg, p, x, policy: RunPolicy, tp: int) -> Tuple[jax.Array, jax.Array]:
     """shard_map expert parallelism for the sorted dispatch (see above)."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     mesh = policy.mesh
@@ -184,13 +183,13 @@ def _moe_sorted_ep(cfg, p, x, policy: RunPolicy, tp: int) -> Tuple[jax.Array, ja
         aux = cfg.num_experts * jnp.sum(me * ce)
         return y.reshape(Bl, Sl, d), aux[None]
 
-    y, aux = shard_map(
+    y, aux = jax.shard_map(
         f, mesh=mesh,
         in_specs=(P(dp_entry, None, None), P(None, None),
                   P("model", None, None), P("model", None, None),
                   P("model", None, None)),
         out_specs=(P(dp_entry, None, None), P(dp_entry)),
-        check_rep=False,
+        check_vma=False,
     )(x, p["router"], p["w_gate"], p["w_up"], p["w_down"])
     return y, aux.mean()
 

@@ -54,13 +54,11 @@ def rowparallel_matmul_q8(x_sharded_contract, w, mesh, *, x_spec: P, w_spec: P,
     x: (B,S,K) with K sharded over 'model'; w: (K, d) sharded on K.
     Returns (B,S,d) replicated over 'model'.
     """
-    from jax.experimental.shard_map import shard_map
-
     def f(x_loc, w_loc):
         y_part = jnp.einsum("bsk,kd->bsd", x_loc, w_loc,
                             preferred_element_type=jnp.float32)
         return quantized_allreduce(y_part, "model").astype(out_dtype)
 
-    return shard_map(f, mesh=mesh, in_specs=(x_spec, w_spec),
-                     out_specs=P(*([None] * 3)), check_rep=False)(
+    return jax.shard_map(f, mesh=mesh, in_specs=(x_spec, w_spec),
+                         out_specs=P(*([None] * 3)), check_vma=False)(
         x_sharded_contract, w)

@@ -15,11 +15,14 @@ import jax.numpy as jnp
 
 
 def adamw_init(params) -> Dict[str, Any]:
-    zeros = lambda p: jnp.zeros(p.shape, jnp.float32)
+    zeros = lambda p: jnp.zeros_like(p, jnp.float32)  # keeps p's sharding
     return {
         "m": jax.tree.map(zeros, params),
         "v": jax.tree.map(zeros, params),
-        "master": jax.tree.map(lambda p: p.astype(jnp.float32), params),
+        # a copy even when params are f32, so params and master never alias
+        # (a donated step state may not hold one buffer twice)
+        "master": jax.tree.map(
+            lambda p: jnp.array(p, jnp.float32, copy=True), params),
         "count": jnp.zeros((), jnp.int32),
     }
 

@@ -49,10 +49,12 @@ serve/metrics.py aggregates the records into SLO reports.
 from __future__ import annotations
 
 import contextlib
+import functools
 from dataclasses import dataclass, field
 from enum import Enum
 from typing import Dict, List, Optional
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -64,6 +66,39 @@ from repro.models.layers import RunPolicy, apply_norm, mlp_apply
 from repro.models import moe as moe_mod
 from repro.models.transformer import embed_in, logits_out, policy_tp
 from repro.serve.paged import PagedKVCache
+
+
+# One transformer layer runs as two jitted programs on either side of the KV
+# pool's scatter and gather (serve/paged.py): one compile per shape, where
+# op-by-op dispatch compiled every op of the layer at every new shape.
+
+
+def _layer_qkv(cfg, lay, p, x, positions):
+    h = apply_norm(cfg.norm, x, p["norm1"])
+    return _project_qkv(cfg, p["mixer"], h, lay, positions)
+
+
+def _layer_rest(cfg, lay, pol, p, x, o):
+    """Residual add of the attention output ``o``, then the FFN block."""
+    x = x + _out_proj(p["mixer"], o, lay)
+    h2 = apply_norm(cfg.norm, x, p["norm2"])
+    if cfg.is_moe:
+        y, _ = moe_mod.moe_apply(cfg, p["ffn"], h2, pol, tp=policy_tp(pol))
+    else:
+        y = mlp_apply(cfg, p["ffn"], h2, pol)
+    return x + y
+
+
+def _prefill_layer_rest(cfg, lay, pol, p, x, q, k_full, v_full, positions,
+                        kpos):
+    o = _sdpa(q, k_full[None], v_full[None], _causal_bias(positions, kpos, 0))
+    return _layer_rest(cfg, lay, pol, p, x, o)
+
+
+def _greedy_next(cfg, pol, params, x):
+    """Greedy next token of each row from its last position: (B,)."""
+    x = apply_norm(cfg.norm, x[:, -1:], params["final_norm"])
+    return jnp.argmax(logits_out(cfg, params, x, pol)[:, -1], axis=-1)
 
 
 class SeqState(Enum):
@@ -135,6 +170,14 @@ class ServeEngine:
         self.params = params
         self.policy = policy or RunPolicy()
         self.layout = kv_head_layout(cfg, policy_tp(self.policy))
+        lay, pol = self.layout, self.policy
+        self._embed = jax.jit(
+            lambda params, toks, pos: embed_in(cfg, params, toks, pol, pos))
+        self._qkv = jax.jit(functools.partial(_layer_qkv, cfg, lay))
+        self._prefill_rest = jax.jit(
+            functools.partial(_prefill_layer_rest, cfg, lay, pol))
+        self._decode_rest = jax.jit(functools.partial(_layer_rest, cfg, lay, pol))
+        self._greedy_next = jax.jit(functools.partial(_greedy_next, cfg, pol))
         # tp_plan (e.g. repro.cluster.serve.ClusterTPPlan) maps sequences to
         # serving superchips and charges per-token tensor-parallel collective
         # traffic; it only ADDS modeled charges and node pins, so generated
@@ -431,38 +474,26 @@ class ServeEngine:
         return chunks
 
     def _prefill_chunk_run(self, req: Request, chunk: int) -> None:
-        cfg, lay, pol = self.cfg, self.layout, self.policy
         s = req.prefill_pos
         e = s + chunk
         self.cache.alloc_range(req.sid, s, e)
-        toks = jnp.asarray(req.prompt[s:e])[None, :]
-        positions = jnp.arange(s, e, dtype=jnp.int32)
-        kpos = jnp.arange(e, dtype=jnp.int32)
-        x = embed_in(cfg, self.params, toks, pol, positions)
-        for i in range(cfg.num_layers):
+        toks = np.asarray(req.prompt[s:e], np.int32)[None, :]
+        positions = np.arange(s, e, dtype=np.int32)
+        kpos = np.arange(e, dtype=np.int32)
+        x = self._embed(self.params, toks, positions)
+        for i in range(self.cfg.num_layers):
             p = self.params["layers"][i]
-            h = apply_norm(cfg.norm, x, p["norm1"])
-            q, k_new, v_new = _project_qkv(cfg, p["mixer"], h, lay, positions)
+            q, k_new, v_new = self._qkv(p, x, positions)
             self.cache.write_at(req.sid, i, k_new[0], v_new[0], s)
             k_full, v_full = self.cache.gather_kv(req.sid, i, e)
-            bias = _causal_bias(positions, kpos, 0)
-            o = _sdpa(q, k_full[None], v_full[None], bias)
-            x = x + _out_proj(p["mixer"], o, lay)
-            h2 = apply_norm(cfg.norm, x, p["norm2"])
-            if cfg.is_moe:
-                y, _ = moe_mod.moe_apply(cfg, p["ffn"], h2, pol, tp=policy_tp(pol))
-            else:
-                y = mlp_apply(cfg, p["ffn"], h2, pol)
-            x = x + y
+            x = self._prefill_rest(p, x, q, k_full, v_full, positions, kpos)
         req.prefill_pos = e
         self.cache.commit_prefill(req.sid, e)
         if self.tp_plan is not None:
             self.tp_plan.on_prefill(self, chunk)
         self.stats.prefill_chunks += 1
         if e == len(req.prompt):
-            x = apply_norm(cfg.norm, x, self.params["final_norm"])
-            logits = logits_out(cfg, self.params, x[:, -1:], pol)
-            req.generated.append(int(jnp.argmax(logits[0, -1])))
+            req.generated.append(int(self._greedy_next(self.params, x)[0]))
             if req.first_token_time is None:
                 req.first_token_time = self.now()
             req.state = SeqState.DECODING
@@ -503,35 +534,24 @@ class ServeEngine:
         return reqs
 
     def _decode_batch(self, reqs: List[Request]) -> None:
-        cfg, lay, pol = self.cfg, self.layout, self.policy
+        cfg, lay = self.cfg, self.layout
+        B = len(reqs)
         sids = [r.sid for r in reqs]
         pos = [int(self.cache.lengths[r.sid]) for r in reqs]
-        tokens = jnp.asarray([[r.generated[-1]] for r in reqs], jnp.int32)
+        tokens = np.asarray([[r.generated[-1]] for r in reqs], np.int32)
+        positions = np.asarray(pos, np.int32)[:, None]
         pt, ln = self.cache.batch_view(sids)
 
-        x = embed_in(cfg, self.params, tokens, pol, jnp.asarray(pos)[:, None])
+        x = self._embed(self.params, tokens, positions)
         for i in range(cfg.num_layers):
             p = self.params["layers"][i]
-            h = apply_norm(cfg.norm, x, p["norm1"])
-            q, k_new, v_new = _project_qkv(cfg, p["mixer"], h, lay,
-                                           jnp.asarray(pos)[:, None])
-            self.cache.write_token(sids, i, np.asarray(k_new[:, 0]),
-                                   np.asarray(v_new[:, 0]), pos)
-            B = len(reqs)
+            q, k_new, v_new = self._qkv(p, x, positions)
+            self.cache.write_token(sids, i, k_new[:, 0], v_new[:, 0], pos)
             qd = q.reshape(B, lay.n_q_eff, cfg.head_dim)
             o = paged_attention(qd, self.cache.k_pools[i], self.cache.v_pools[i],
                                 pt, ln + 1)
-            o = _out_proj(p["mixer"], o[:, None], lay)
-            x = x + o
-            h2 = apply_norm(cfg.norm, x, p["norm2"])
-            if cfg.is_moe:
-                y, _ = moe_mod.moe_apply(cfg, p["ffn"], h2, pol, tp=policy_tp(pol))
-            else:
-                y = mlp_apply(cfg, p["ffn"], h2, pol)
-            x = x + y
-        x = apply_norm(cfg.norm, x, self.params["final_norm"])
-        logits = logits_out(cfg, self.params, x, pol)
-        nxt = np.asarray(jnp.argmax(logits[:, 0], axis=-1))
+            x = self._decode_rest(p, x, o[:, None])
+        nxt = np.asarray(self._greedy_next(self.params, x))
         self.cache.commit_token(sids, pos)
         if self.tp_plan is not None:
             self.tp_plan.on_decode(self, len(reqs))

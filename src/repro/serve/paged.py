@@ -16,6 +16,11 @@ swapped out host-side (``swap_out``) and scattered back on resume
 (``swap_in``), at which point the access-counter path re-promotes their
 pages.
 
+Each layer's pool is head-major, (num_pages, N, page_size, D): one page of
+one KV head is a contiguous (page_size, D) tile, the block the paged
+attention kernel DMAs per grid step. Writes and gathers index (page, :,
+slot), so callers still see KV as (tokens, N, D).
+
 Write paths are vectorized: a whole prefill chunk lands in one fancy-index
 scatter (no per-page Python loop, no ``dynamic_update_slice``), sliced to
 the real block length so partial pages never zero-pad into the pool.
@@ -60,9 +65,9 @@ class PagedKVCache:
         self.num_pages = num_pages or (max_seqs * self.pages_per_seq + 1)
         N, D = layout.n_kv_eff, cfg.head_dim
         L = cfg.num_layers
-        self.k_pools = [jnp.zeros((self.num_pages, page_size, N, D), dtype)
+        self.k_pools = [jnp.zeros((self.num_pages, N, page_size, D), dtype)
                         for _ in range(L)]
-        self.v_pools = [jnp.zeros((self.num_pages, page_size, N, D), dtype)
+        self.v_pools = [jnp.zeros((self.num_pages, N, page_size, D), dtype)
                         for _ in range(L)]
         self.page_table = np.zeros((max_seqs, self.pages_per_seq), np.int32)
         self.lengths = np.zeros((max_seqs,), np.int32)
@@ -168,8 +173,8 @@ class PagedKVCache:
         tail page is never zero-padded)."""
         S = k.shape[0]
         pids, slots = self._flat_idx(sid, start, S)
-        self.k_pools[layer] = self.k_pools[layer].at[pids, slots].set(k)
-        self.v_pools[layer] = self.v_pools[layer].at[pids, slots].set(v)
+        self.k_pools[layer] = self.k_pools[layer].at[pids, :, slots].set(k)
+        self.v_pools[layer] = self.v_pools[layer].at[pids, :, slots].set(v)
 
     def write_prefill(self, sid: int, layer: int, k, v) -> None:
         """k, v: (S, N, D) for one sequence; fills positions [0, S)."""
@@ -190,8 +195,8 @@ class PagedKVCache:
         pids = self.page_table[sids, pos // self.page_size]
         assert (pids != 0).all(), "decode write into unallocated page"
         slots = pos % self.page_size
-        self.k_pools[layer] = self.k_pools[layer].at[pids, slots].set(k)
-        self.v_pools[layer] = self.v_pools[layer].at[pids, slots].set(v)
+        self.k_pools[layer] = self.k_pools[layer].at[pids, :, slots].set(k)
+        self.v_pools[layer] = self.v_pools[layer].at[pids, :, slots].set(v)
 
     def commit_token(self, sid_list, pos_list) -> None:
         # lengths first, then one batched engine step over every decoded
@@ -215,7 +220,8 @@ class PagedKVCache:
     def gather_kv(self, sid: int, layer: int, length: int):
         """Gather positions [0, length) of sequence sid -> (length, N, D) pair."""
         pids, slots = self._flat_idx(sid, 0, length)
-        return self.k_pools[layer][pids, slots], self.v_pools[layer][pids, slots]
+        return (self.k_pools[layer][pids, :, slots],
+                self.v_pools[layer][pids, :, slots])
 
     # ------------------------------------------------------------- swap
     def swap_out(self, sid: int) -> Dict[str, object]:

@@ -47,6 +47,20 @@ def make_train_state(cfg: ArchConfig, params) -> Dict[str, Any]:
     return {"params": params, "opt": adamw_init(params), "step": jnp.zeros((), jnp.int32)}
 
 
+def split_microbatches(batch, accum: int):
+    """(B, ...) leaves -> (accum, B/accum, ...); microbatch i takes rows
+    i, i + accum, i + 2*accum, ...
+
+    Splitting the batch axis as (B/accum, accum) and moving ``accum`` to the
+    front keeps a 'data' sharding of the batch on the per-microbatch axis.
+    The contiguous (accum, B/accum) split would put it on the leading axis,
+    which ``lax.scan`` requires to be replicated."""
+    return jax.tree.map(
+        lambda x: jnp.moveaxis(
+            x.reshape((x.shape[0] // accum, accum) + x.shape[1:]), 1, 0),
+        batch)
+
+
 def make_train_step(cfg: ArchConfig, policy: RunPolicy, tc: TrainerConfig,
                     grad_spec_constrain: Optional[Callable] = None):
     """Returns step(state, batch, [err]) -> (state, metrics[, err]).
@@ -80,8 +94,7 @@ def make_train_step(cfg: ArchConfig, policy: RunPolicy, tc: TrainerConfig,
                     lambda a, gg: a + gg.astype(jnp.float32), gacc, g))
                 return (gacc, lacc + l), None
 
-            mb_tree = jax.tree.map(
-                lambda x: x.reshape((accum, B // accum) + x.shape[1:]), batch)
+            mb_tree = split_microbatches(batch, accum)
             gacc0 = constrain(jax.tree.map(
                 lambda p: jnp.zeros(p.shape, jnp.float32), params))
             (grads, loss_sum), _ = jax.lax.scan(micro, (gacc0, 0.0), mb_tree)

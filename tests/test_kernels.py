@@ -43,8 +43,8 @@ def test_flash_attention(B, S, H, Hkv, D, dtype, window):
 def test_paged_attention(B, H, Hkv, D, P, PS, NP, dtype):
     ks = jax.random.split(jax.random.PRNGKey(1), 4)
     q = jax.random.normal(ks[0], (B, H, D), dtype)
-    kp = jax.random.normal(ks[1], (P, PS, Hkv, D), dtype)
-    vp = jax.random.normal(ks[2], (P, PS, Hkv, D), dtype)
+    kp = jax.random.normal(ks[1], (P, Hkv, PS, D), dtype)
+    vp = jax.random.normal(ks[2], (P, Hkv, PS, D), dtype)
     pt = jax.random.permutation(ks[3], P)[:B * NP].reshape(B, NP).astype(jnp.int32)
     lengths = jnp.asarray([NP * PS - 3] + [max(1, (NP - 1) * PS)] * (B - 1),
                           jnp.int32)[:B]

@@ -45,10 +45,18 @@ _SCRIPT = textwrap.dedent("""
         state, metrics = step(state, batch)
     loss = float(metrics["loss"])
     assert np.isfinite(loss), loss
-    # params sharded as requested
-    wq = state["params"]["layers"][0]["mixer"].get("wq")
-    if wq is not None:
-        assert len(wq.sharding.device_set) == 8 or True
+    # every param the specs split comes out of the step split the same way
+    # (replicated ones the partitioner may place as it likes)
+    leaves = jax.tree.leaves(state["params"])
+    specs = jax.tree.leaves(pspec, is_leaf=lambda x: isinstance(x, P))
+    assert len(leaves) == len(specs)
+    split = [(leaf, spec) for leaf, spec in zip(leaves, specs)
+             if any(ax is not None for ax in spec)]
+    assert split, "no parameter is sharded"
+    for leaf, spec in split:
+        assert leaf.sharding.is_equivalent_to(NamedSharding(mesh, spec),
+                                              leaf.ndim), (leaf.sharding, spec)
+        assert leaf.sharding.shard_shape(leaf.shape) != leaf.shape
     print("SHARDED_OK", loss)
 """)
 

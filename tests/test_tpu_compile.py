@@ -1,0 +1,74 @@
+"""The Pallas kernels compile for a TPU v5e at the widths they run at.
+
+Nothing runs: each test lowers a kernel for a described (not attached) v5e
+chip and compiles it with the TPU compiler, which refuses what interpret
+mode accepts (misaligned blocks, more VMEM than a kernel may use). The
+topology is described inside a fixture, so only the worker that runs these
+tests loads the TPU library; where it cannot be described the tests skip.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels.paged_attention import paged_attention
+from repro.kernels.qv_gate import apply_two_qubit_gate
+from repro.kernels.stencil5 import stencil5
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler here: nothing to check
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip cannot be read back from the persistent
+    # cache, so keep these out of it
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+
+
+def _sds(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _assert_kernel(compiled):
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+# (H, Hkv, D): yi-6b decode widths, and a GQA shape with D=64
+@pytest.mark.parametrize("H,Hkv,D", [(32, 4, 128), (24, 8, 64)])
+@pytest.mark.parametrize("q_dtype,kv_dtype", [
+    (jnp.bfloat16, jnp.bfloat16),
+    (jnp.float32, jnp.float32),
+    (jnp.bfloat16, jnp.float32),  # the serve engine: bf16 model, f32 pool
+])
+def test_paged_attention_compiles(one_chip, H, Hkv, D, q_dtype, kv_dtype):
+    B, PS, NP = 8, 64, 16
+    P = B * NP + 1
+    args = (_sds((B, H, D), q_dtype, one_chip),
+            _sds((P, Hkv, PS, D), kv_dtype, one_chip),
+            _sds((P, Hkv, PS, D), kv_dtype, one_chip),
+            _sds((B, NP), jnp.int32, one_chip),
+            _sds((B,), jnp.int32, one_chip))
+    _assert_kernel(paged_attention.lower(*args, interpret=False).compile())
+
+
+def test_stencil5_compiles_at_4096(one_chip):
+    grid = _sds((4096, 4096), jnp.float32, one_chip)
+    _assert_kernel(stencil5.lower(grid, 0.1, interpret=False).compile())
+
+
+def test_qv_gate_compiles_at_qsim_fig3(one_chip):
+    n = 16  # apps/qsim.py "fig3" size
+    state = _sds((2 ** n,), jnp.complex64, one_chip)
+    gate = _sds((4, 4), jnp.complex64, one_chip)
+    _assert_kernel(apply_two_qubit_gate.lower(
+        state, gate, 3, 11, n, interpret=False).compile())

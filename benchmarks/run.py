@@ -48,7 +48,6 @@ MODULES = [
     "benchmarks.fig10_srad_migration",
     "benchmarks.fig11_oversub",
     "benchmarks.fig1213_prefetch",
-    "benchmarks.kernels_micro",
     "benchmarks.lm_serve_paged",
     "benchmarks.lm_roofline",
     "benchmarks.sim_throughput",

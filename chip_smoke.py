@@ -32,7 +32,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-sys.path.insert(0, str(Path(__file__).resolve().parent / "src"))
+ROOT = Path(__file__).resolve().parent
+sys.path[:0] = [str(ROOT), str(ROOT / "src")]
+
+from bench.clock import compile_clock  # noqa: E402
 
 ARCH = "yi-6b"
 # paged_attention returns q's dtype: the bf16 output rounds by up to 2^-9
@@ -52,25 +55,6 @@ def check(ok: bool, what) -> None:
     """A failed check ends the run (unlike assert, also under python -O)."""
     if not ok:
         raise RuntimeError(f"chip_smoke check failed: {what}")
-
-
-class CompileClock:
-    """Sums XLA backend compile time (a persistent-cache hit counts only its
-    read) and counts persistent-cache hits, from JAX's monitoring events."""
-
-    def __init__(self):
-        self.seconds = 0.0
-        self.cache_hits = 0
-        jax.monitoring.register_event_duration_secs_listener(self._duration)
-        jax.monitoring.register_event_listener(self._event)
-
-    def _duration(self, event, duration, **_):
-        if event == "/jax/core/compile/backend_compile_duration":
-            self.seconds += duration
-
-    def _event(self, event, **_):
-        if event == "/jax/compilation_cache/cache_hits":
-            self.cache_hits += 1
 
 
 def check_paged_attention() -> None:
@@ -147,7 +131,7 @@ def main() -> int:
     from repro.launch.compile_cache import enable_compile_cache
 
     print(f"compile cache: {enable_compile_cache()}")
-    clock = CompileClock()
+    clock = compile_clock()
     phases = ([train_sharded_vs_one_chip] if args.chips == 4
               else [check_paged_attention, serve_yi6b])
     for phase in phases:

@@ -41,10 +41,12 @@ system (see ``Mi300aUnifiedPolicy``) plugs in through
 from __future__ import annotations
 
 import contextlib
+import functools
 from dataclasses import dataclass
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
+from jax.profiler import annotate_function
 
 from repro.core.buffer import BufferView, UMBuffer, as_view
 from repro.core.hardware import GRACE_HOPPER, HardwareModel
@@ -126,6 +128,12 @@ class KernelBatch:
 
     def __len__(self) -> int:
         return len(self.items)
+
+
+def _span(name: str):
+    """Run the method inside the host span ``name`` (``TraceAnnotation``, on
+    the profiler's clock): the calls the serve engine makes each step."""
+    return functools.partial(annotate_function, name=name)
 
 
 class UnifiedMemory:
@@ -353,6 +361,7 @@ class UnifiedMemory:
         buf.host = policy.make_staging(self, buf)
         return buf
 
+    @_span("umem.launch")
     def launch(self, name: Optional[str] = None, *, reads: Sequence = (),
                writes: Sequence = (), flops: float = 0.0,
                actor: Actor = Actor.GPU, node: Optional[int] = None) -> float:
@@ -372,6 +381,7 @@ class UnifiedMemory:
             writes=[_as_range(w, actor) for w in writes],
             flops=flops, actor=actor, name=name, node=node)
 
+    @_span("umem.launch_batch")
     def launch_batch(self, batch) -> List[float]:
         """Submit a whole batch of launches in one engine step.
 
@@ -1019,6 +1029,7 @@ class UnifiedMemory:
         return total
 
     # ------------------------------------------------------------- sync/misc
+    @_span("umem.sync")
     def sync(self) -> float:
         """cudaDeviceSynchronize analogue: each live paged allocation's
         policy drains whatever it batches to sync points (the system
@@ -1081,6 +1092,7 @@ class UnifiedMemory:
         self._sample()
         return self.clock - t0
 
+    @_span("umem.prefetch_async")
     def prefetch_async(self, ranges: Sequence) -> float:
         """Async multi-extent prefetch: promote each item — a raw
         (alloc, lo, hi) range or a BufferView — to the device ahead of the
@@ -1094,6 +1106,7 @@ class UnifiedMemory:
             self.prefetch(a, lo, hi, overlap=True)
         return self._pending_overlap - before
 
+    @_span("umem.demote")
     def demote(self, a, lo: Optional[int] = None,
                hi: Optional[int] = None) -> float:
         """Demote a range host-side (cudaMemPrefetchAsync-to-cpuDeviceId

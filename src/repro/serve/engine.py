@@ -45,11 +45,32 @@ request spends waiting for the admission gate; measuring from admission
 would understate exactly the tail the SLO metrics exist to expose.
 serve/traffic.py drives arrival processes against this clock and
 serve/metrics.py aggregates the records into SLO reports.
+
+Beside the modeled stamps each request carries wall-clock stamps
+(``time.perf_counter()``): ``arrival_wall`` at enqueue, ``prefill_wall`` when
+its first prefill chunk starts and ``first_token_wall`` when its first token
+is sampled. ``prefill_wall - arrival_wall`` is the wait for the admission
+gate and the shared prefill budget; ``first_token_wall - prefill_wall`` the
+prefill itself.
+
+**Spans.** The engine's phases run inside host spans
+(``jax.profiler.TraceAnnotation``, on the profiler's clock, a no-op costing
+about a microsecond while no trace is taken): ``serve.step`` (arg ``step``)
+around each step, ``serve.admit``, ``serve.prefill`` (``rid``, ``start``,
+``end``) around a prefill chunk, ``serve.decode`` (``batch``) around a decode
+batch, and inside those ``serve.embed``, ``serve.qkv``, ``serve.attention``,
+``serve.layer_rest`` and ``serve.sample`` around the dispatch of each
+program. The KV pool adds ``serve.kv_write``/``kv_gather``/``kv_view``
+(serve/paged.py) and the memory model ``umem.*`` (core/umem.py). The jitted
+programs carry names of their own, so the device trace shows ``jit_embed``,
+``jit_layer_qkv``, ``jit_prefill_layer_rest``, ``jit_layer_rest`` and
+``jit_greedy_next``.
 """
 from __future__ import annotations
 
 import contextlib
 import functools
+import time
 from dataclasses import dataclass, field
 from enum import Enum
 from typing import Dict, List, Optional
@@ -57,6 +78,7 @@ from typing import Dict, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core import HostSpillError, UnifiedMemory
 from repro.kernels.paged_attention import paged_attention
@@ -71,6 +93,10 @@ from repro.serve.paged import PagedKVCache
 # One transformer layer runs as two jitted programs on either side of the KV
 # pool's scatter and gather (serve/paged.py): one compile per shape, where
 # op-by-op dispatch compiled every op of the layer at every new shape.
+
+
+def _embed(cfg, pol, params, toks, positions):
+    return embed_in(cfg, params, toks, pol, positions)
 
 
 def _layer_qkv(cfg, lay, p, x, positions):
@@ -101,6 +127,14 @@ def _greedy_next(cfg, pol, params, x):
     return jnp.argmax(logits_out(cfg, params, x, pol)[:, -1], axis=-1)
 
 
+def _program(name, fn, *bound):
+    """``fn`` with its leading arguments bound, jitted under ``name``: the
+    device trace shows ``jit_<name>`` (a bare partial shows ``jit__unknown``)."""
+    f = functools.partial(fn, *bound)
+    f.__name__ = name
+    return jax.jit(f)
+
+
 class SeqState(Enum):
     PENDING = "pending"      # not yet admitted
     PREFILL = "prefill"      # admitted, prompt partially prefilled
@@ -129,6 +163,11 @@ class Request:
     admit_time: Optional[float] = None
     first_token_time: Optional[float] = None
     finish_time: Optional[float] = None
+    # wall-clock stamps (time.perf_counter()): enqueue, start of the first
+    # prefill chunk, first token
+    arrival_wall: float = 0.0
+    prefill_wall: Optional[float] = None
+    first_token_wall: Optional[float] = None
 
     @property
     def done(self) -> bool:
@@ -171,13 +210,12 @@ class ServeEngine:
         self.policy = policy or RunPolicy()
         self.layout = kv_head_layout(cfg, policy_tp(self.policy))
         lay, pol = self.layout, self.policy
-        self._embed = jax.jit(
-            lambda params, toks, pos: embed_in(cfg, params, toks, pol, pos))
-        self._qkv = jax.jit(functools.partial(_layer_qkv, cfg, lay))
-        self._prefill_rest = jax.jit(
-            functools.partial(_prefill_layer_rest, cfg, lay, pol))
-        self._decode_rest = jax.jit(functools.partial(_layer_rest, cfg, lay, pol))
-        self._greedy_next = jax.jit(functools.partial(_greedy_next, cfg, pol))
+        self._embed = _program("embed", _embed, cfg, pol)
+        self._qkv = _program("layer_qkv", _layer_qkv, cfg, lay)
+        self._prefill_rest = _program("prefill_layer_rest",
+                                      _prefill_layer_rest, cfg, lay, pol)
+        self._decode_rest = _program("layer_rest", _layer_rest, cfg, lay, pol)
+        self._greedy_next = _program("greedy_next", _greedy_next, cfg, pol)
         # tp_plan (e.g. repro.cluster.serve.ClusterTPPlan) maps sequences to
         # serving superchips and charges per-token tensor-parallel collective
         # traffic; it only ADDS modeled charges and node pins, so generated
@@ -248,7 +286,8 @@ class ServeEngine:
         # enqueue time IS the arrival: TTFT must cover pre-admission queueing
         self.requests[rid] = Request(
             rid, np.asarray(prompt), max_new_tokens, tenant=tenant,
-            arrival_time=self.now() if arrival_time is None else arrival_time)
+            arrival_time=self.now() if arrival_time is None else arrival_time,
+            arrival_wall=time.perf_counter())
         return rid
 
     def _in_state(self, state: SeqState) -> List[Request]:
@@ -476,30 +515,41 @@ class ServeEngine:
     def _prefill_chunk_run(self, req: Request, chunk: int) -> None:
         s = req.prefill_pos
         e = s + chunk
-        self.cache.alloc_range(req.sid, s, e)
-        toks = np.asarray(req.prompt[s:e], np.int32)[None, :]
-        positions = np.arange(s, e, dtype=np.int32)
-        kpos = np.arange(e, dtype=np.int32)
-        x = self._embed(self.params, toks, positions)
-        for i in range(self.cfg.num_layers):
-            p = self.params["layers"][i]
-            q, k_new, v_new = self._qkv(p, x, positions)
-            self.cache.write_at(req.sid, i, k_new[0], v_new[0], s)
-            k_full, v_full = self.cache.gather_kv(req.sid, i, e)
-            x = self._prefill_rest(p, x, q, k_full, v_full, positions, kpos)
-        req.prefill_pos = e
-        self.cache.commit_prefill(req.sid, e)
-        if self.tp_plan is not None:
-            self.tp_plan.on_prefill(self, chunk)
-        self.stats.prefill_chunks += 1
-        if e == len(req.prompt):
-            req.generated.append(int(self._greedy_next(self.params, x)[0]))
-            if req.first_token_time is None:
-                req.first_token_time = self.now()
-            req.state = SeqState.DECODING
-            if (len(req.generated) >= req.max_new_tokens
-                    or len(req.prompt) + len(req.generated) >= self.max_len - 1):
-                self._finish(req)
+        if req.prefill_wall is None:
+            req.prefill_wall = time.perf_counter()
+        with TraceAnnotation("serve.prefill", rid=req.rid, start=s, end=e):
+            self.cache.alloc_range(req.sid, s, e)
+            toks = np.asarray(req.prompt[s:e], np.int32)[None, :]
+            positions = np.arange(s, e, dtype=np.int32)
+            kpos = np.arange(e, dtype=np.int32)
+            with TraceAnnotation("serve.embed"):
+                x = self._embed(self.params, toks, positions)
+            for i in range(self.cfg.num_layers):
+                p = self.params["layers"][i]
+                with TraceAnnotation("serve.qkv"):
+                    q, k_new, v_new = self._qkv(p, x, positions)
+                self.cache.write_at(req.sid, i, k_new[0], v_new[0], s)
+                k_full, v_full = self.cache.gather_kv(req.sid, i, e)
+                with TraceAnnotation("serve.layer_rest"):
+                    x = self._prefill_rest(p, x, q, k_full, v_full, positions,
+                                           kpos)
+            req.prefill_pos = e
+            self.cache.commit_prefill(req.sid, e)
+            if self.tp_plan is not None:
+                self.tp_plan.on_prefill(self, chunk)
+            self.stats.prefill_chunks += 1
+            if e == len(req.prompt):
+                with TraceAnnotation("serve.sample"):
+                    req.generated.append(
+                        int(self._greedy_next(self.params, x)[0]))
+                if req.first_token_time is None:
+                    req.first_token_time = self.now()
+                    req.first_token_wall = time.perf_counter()
+                req.state = SeqState.DECODING
+                if (len(req.generated) >= req.max_new_tokens
+                        or len(req.prompt) + len(req.generated)
+                        >= self.max_len - 1):
+                    self._finish(req)
 
     # --------------------------------------------------------------- decode
     def _ensure_decode_pages(self, reqs: List[Request]) -> List[Request]:
@@ -536,32 +586,39 @@ class ServeEngine:
     def _decode_batch(self, reqs: List[Request]) -> None:
         cfg, lay = self.cfg, self.layout
         B = len(reqs)
-        sids = [r.sid for r in reqs]
-        pos = [int(self.cache.lengths[r.sid]) for r in reqs]
-        tokens = np.asarray([[r.generated[-1]] for r in reqs], np.int32)
-        positions = np.asarray(pos, np.int32)[:, None]
-        pt, ln = self.cache.batch_view(sids)
+        with TraceAnnotation("serve.decode", batch=B):
+            sids = [r.sid for r in reqs]
+            pos = [int(self.cache.lengths[r.sid]) for r in reqs]
+            tokens = np.asarray([[r.generated[-1]] for r in reqs], np.int32)
+            positions = np.asarray(pos, np.int32)[:, None]
+            pt, ln = self.cache.batch_view(sids)
 
-        x = self._embed(self.params, tokens, positions)
-        for i in range(cfg.num_layers):
-            p = self.params["layers"][i]
-            q, k_new, v_new = self._qkv(p, x, positions)
-            self.cache.write_token(sids, i, k_new[:, 0], v_new[:, 0], pos)
-            qd = q.reshape(B, lay.n_q_eff, cfg.head_dim)
-            o = paged_attention(qd, self.cache.k_pools[i], self.cache.v_pools[i],
-                                pt, ln + 1)
-            x = self._decode_rest(p, x, o[:, None])
-        nxt = np.asarray(self._greedy_next(self.params, x))
-        self.cache.commit_token(sids, pos)
-        if self.tp_plan is not None:
-            self.tp_plan.on_decode(self, len(reqs))
-        self.stats.decode_batches += 1
-        self.stats.decode_tokens += len(reqs)
-        for r, t in zip(reqs, nxt):
-            r.generated.append(int(t))
-            total = len(r.prompt) + len(r.generated)
-            if len(r.generated) >= r.max_new_tokens or total >= self.max_len - 1:
-                self._finish(r)
+            with TraceAnnotation("serve.embed"):
+                x = self._embed(self.params, tokens, positions)
+            for i in range(cfg.num_layers):
+                p = self.params["layers"][i]
+                with TraceAnnotation("serve.qkv"):
+                    q, k_new, v_new = self._qkv(p, x, positions)
+                self.cache.write_token(sids, i, k_new[:, 0], v_new[:, 0], pos)
+                with TraceAnnotation("serve.attention"):
+                    qd = q.reshape(B, lay.n_q_eff, cfg.head_dim)
+                    o = paged_attention(qd, self.cache.k_pools[i],
+                                        self.cache.v_pools[i], pt, ln + 1)
+                with TraceAnnotation("serve.layer_rest"):
+                    x = self._decode_rest(p, x, o[:, None])
+            with TraceAnnotation("serve.sample"):
+                nxt = np.asarray(self._greedy_next(self.params, x))
+            self.cache.commit_token(sids, pos)
+            if self.tp_plan is not None:
+                self.tp_plan.on_decode(self, len(reqs))
+            self.stats.decode_batches += 1
+            self.stats.decode_tokens += len(reqs)
+            for r, t in zip(reqs, nxt):
+                r.generated.append(int(t))
+                total = len(r.prompt) + len(r.generated)
+                if (len(r.generated) >= r.max_new_tokens
+                        or total >= self.max_len - 1):
+                    self._finish(r)
 
     def _finish(self, req: Request) -> None:
         req.state = SeqState.DONE
@@ -583,36 +640,39 @@ class ServeEngine:
     def step(self) -> bool:
         """One engine step: admit/resume, chunked prefill, prefetch, decode.
         Returns True while any request is in flight."""
-        if self.fault_plan is not None:
-            self._apply_faults()
-        pre0 = self.stats.preempted
-        rec0 = self.stats.recovered_requests
-        progress = 0
-        if self._hold_admit > 0:
-            # the post-fault backoff window ticking down IS forward motion:
-            # held admissions land when it expires
-            self._hold_admit -= 1
-            progress += 1
-            if self._hold_admit == 0:
-                self._backoff = self.admit_backoff_steps
-        progress += self._admit()
-        progress += self._prefill_step()
-        decoding = self._in_state(SeqState.DECODING)
-        if decoding:
-            batch = self._ensure_decode_pages(decoding)
-            if batch:
-                self._prefetch_resumed()
-                self._decode_batch(batch)
-                progress += len(batch)
-        # a preemption frees pages for next step's admit/prefill/decode, so it
-        # counts as progress (a genuine deadlock preempts nothing either);
-        # likewise a fault replay requeues real work for the next step
-        progress += self.stats.preempted - pre0
-        progress += self.stats.recovered_requests - rec0
-        if self.um is not None:
-            self.um.sync()  # sync point: apply counter-driven delayed migrations
-        self._steps += 1
-        in_flight = self._in_flight()
+        with TraceAnnotation("serve.step", step=self._steps):
+            if self.fault_plan is not None:
+                self._apply_faults()
+            pre0 = self.stats.preempted
+            rec0 = self.stats.recovered_requests
+            progress = 0
+            if self._hold_admit > 0:
+                # the post-fault backoff window ticking down IS forward
+                # motion: held admissions land when it expires
+                self._hold_admit -= 1
+                progress += 1
+                if self._hold_admit == 0:
+                    self._backoff = self.admit_backoff_steps
+            with TraceAnnotation("serve.admit"):
+                progress += self._admit()
+            progress += self._prefill_step()
+            decoding = self._in_state(SeqState.DECODING)
+            if decoding:
+                batch = self._ensure_decode_pages(decoding)
+                if batch:
+                    self._prefetch_resumed()
+                    self._decode_batch(batch)
+                    progress += len(batch)
+            # a preemption frees pages for next step's admit/prefill/decode,
+            # so it counts as progress (a genuine deadlock preempts nothing
+            # either); likewise a fault replay requeues real work for the
+            # next step
+            progress += self.stats.preempted - pre0
+            progress += self.stats.recovered_requests - rec0
+            if self.um is not None:
+                self.um.sync()  # sync point: apply counter-driven delayed migrations
+            self._steps += 1
+            in_flight = self._in_flight()
         if in_flight and progress == 0:
             raise RuntimeError(
                 "scheduler stalled: KV pool cannot back any in-flight request "

@@ -24,6 +24,10 @@ slot), so callers still see KV as (tokens, N, D).
 Write paths are vectorized: a whole prefill chunk lands in one fancy-index
 scatter (no per-page Python loop, no ``dynamic_update_slice``), sliced to
 the real block length so partial pages never zero-pad into the pool.
+
+Writes, gathers and the decode batch's page-table view run inside host
+spans (``serve.kv_write``, ``serve.kv_gather``, ``serve.kv_view``; see the
+"Spans" part of serve/engine.py's docstring).
 """
 from __future__ import annotations
 
@@ -31,6 +35,7 @@ from typing import Dict, List, Optional, Tuple
 
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core import (Actor, BufferView, KernelBatch, MemPolicy,
                         UnifiedMemory, coalesce_runs, make_policy,
@@ -171,10 +176,10 @@ class PagedKVCache:
         k, v: (S, N, D). One fancy-index scatter per pool — every page of the
         chunk lands at once, and the update covers exactly S slots (a partial
         tail page is never zero-padded)."""
-        S = k.shape[0]
-        pids, slots = self._flat_idx(sid, start, S)
-        self.k_pools[layer] = self.k_pools[layer].at[pids, :, slots].set(k)
-        self.v_pools[layer] = self.v_pools[layer].at[pids, :, slots].set(v)
+        with TraceAnnotation("serve.kv_write"):
+            pids, slots = self._flat_idx(sid, start, k.shape[0])
+            self.k_pools[layer] = self.k_pools[layer].at[pids, :, slots].set(k)
+            self.v_pools[layer] = self.v_pools[layer].at[pids, :, slots].set(v)
 
     def write_prefill(self, sid: int, layer: int, k, v) -> None:
         """k, v: (S, N, D) for one sequence; fills positions [0, S)."""
@@ -190,13 +195,14 @@ class PagedKVCache:
 
     def write_token(self, sid_list, layer: int, k, v, pos_list) -> None:
         """k, v: (B, N, D) new-token KV for sequences sid_list at pos_list."""
-        sids = np.asarray(sid_list)
-        pos = np.asarray(pos_list)
-        pids = self.page_table[sids, pos // self.page_size]
-        assert (pids != 0).all(), "decode write into unallocated page"
-        slots = pos % self.page_size
-        self.k_pools[layer] = self.k_pools[layer].at[pids, :, slots].set(k)
-        self.v_pools[layer] = self.v_pools[layer].at[pids, :, slots].set(v)
+        with TraceAnnotation("serve.kv_write"):
+            sids = np.asarray(sid_list)
+            pos = np.asarray(pos_list)
+            pids = self.page_table[sids, pos // self.page_size]
+            assert (pids != 0).all(), "decode write into unallocated page"
+            slots = pos % self.page_size
+            self.k_pools[layer] = self.k_pools[layer].at[pids, :, slots].set(k)
+            self.v_pools[layer] = self.v_pools[layer].at[pids, :, slots].set(v)
 
     def commit_token(self, sid_list, pos_list) -> None:
         # lengths first, then one batched engine step over every decoded
@@ -219,9 +225,10 @@ class PagedKVCache:
     # ------------------------------------------------------------- reads
     def gather_kv(self, sid: int, layer: int, length: int):
         """Gather positions [0, length) of sequence sid -> (length, N, D) pair."""
-        pids, slots = self._flat_idx(sid, 0, length)
-        return (self.k_pools[layer][pids, :, slots],
-                self.v_pools[layer][pids, :, slots])
+        with TraceAnnotation("serve.kv_gather"):
+            pids, slots = self._flat_idx(sid, 0, length)
+            return (self.k_pools[layer][pids, :, slots],
+                    self.v_pools[layer][pids, :, slots])
 
     # ------------------------------------------------------------- swap
     def swap_out(self, sid: int) -> Dict[str, object]:
@@ -306,6 +313,6 @@ class PagedKVCache:
 
     # ------------------------------------------------------------- views
     def batch_view(self, sids):
-        pt = jnp.asarray(self.page_table[sids])
-        ln = jnp.asarray(self.lengths[sids])
-        return pt, ln
+        with TraceAnnotation("serve.kv_view"):
+            return (jnp.asarray(self.page_table[sids]),
+                    jnp.asarray(self.lengths[sids]))

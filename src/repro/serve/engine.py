@@ -64,7 +64,7 @@ program. The KV pool adds ``serve.kv_write``/``kv_gather``/``kv_view``
 (serve/paged.py) and the memory model ``umem.*`` (core/umem.py). The jitted
 programs carry names of their own, so the device trace shows ``jit_embed``,
 ``jit_layer_qkv``, ``jit_prefill_layer_rest``, ``jit_layer_rest`` and
-``jit_greedy_next``.
+``jit_greedy_next``, and the KV pool's ``jit_kv_write`` and ``jit_kv_gather``.
 """
 from __future__ import annotations
 
@@ -91,8 +91,8 @@ from repro.serve.paged import PagedKVCache
 
 
 # One transformer layer runs as two jitted programs on either side of the KV
-# pool's scatter and gather (serve/paged.py): one compile per shape, where
-# op-by-op dispatch compiled every op of the layer at every new shape.
+# pool's jitted write and gather (serve/paged.py): one compile per shape,
+# where op-by-op dispatch compiled every op of the layer at every new shape.
 
 
 def _embed(cfg, pol, params, toks, positions):
@@ -528,7 +528,7 @@ class ServeEngine:
                 p = self.params["layers"][i]
                 with TraceAnnotation("serve.qkv"):
                     q, k_new, v_new = self._qkv(p, x, positions)
-                self.cache.write_at(req.sid, i, k_new[0], v_new[0], s)
+                self.cache.write_at(req.sid, i, k_new, v_new, s)
                 k_full, v_full = self.cache.gather_kv(req.sid, i, e)
                 with TraceAnnotation("serve.layer_rest"):
                     x = self._prefill_rest(p, x, q, k_full, v_full, positions,
@@ -599,7 +599,7 @@ class ServeEngine:
                 p = self.params["layers"][i]
                 with TraceAnnotation("serve.qkv"):
                     q, k_new, v_new = self._qkv(p, x, positions)
-                self.cache.write_token(sids, i, k_new[:, 0], v_new[:, 0], pos)
+                self.cache.write_token(sids, i, k_new, v_new, pos)
                 with TraceAnnotation("serve.attention"):
                     qd = q.reshape(B, lay.n_q_eff, cfg.head_dim)
                     o = paged_attention(qd, self.cache.k_pools[i],

@@ -21,9 +21,17 @@ one KV head is a contiguous (page_size, D) tile, the block the paged
 attention kernel DMAs per grid step. Writes and gathers index (page, :,
 slot), so callers still see KV as (tokens, N, D).
 
-Write paths are vectorized: a whole prefill chunk lands in one fancy-index
-scatter (no per-page Python loop, no ``dynamic_update_slice``), sliced to
-the real block length so partial pages never zero-pad into the pool.
+Every write and gather is one jitted program over a layer's K and V pools
+(``kv_write``, ``kv_gather``: the device trace shows ``jit_kv_write`` and
+``jit_kv_gather``). The write donates the pools, so its scatter runs in
+place instead of copying a whole pool, and covers exactly the tokens given:
+a partial tail page is never padded. The layer is no argument of either
+program, so one compile per (token count, pool shape) serves every layer.
+Their page and slot indices are checked and built on the host, then
+uploaded once and reused by every layer that asks for the same ones: a
+decode batch uploads one index pair for all layers, a prefill chunk one for
+its writes and one for its prefix gathers (``kv_index_uploads`` against
+``kv_pool_calls``).
 
 Writes, gathers and the decode batch's page-table view run inside host
 spans (``serve.kv_write``, ``serve.kv_gather``, ``serve.kv_view``; see the
@@ -31,8 +39,10 @@ spans (``serve.kv_write``, ``serve.kv_gather``, ``serve.kv_view``; see the
 """
 from __future__ import annotations
 
+import functools
 from typing import Dict, List, Optional, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.profiler import TraceAnnotation
@@ -41,6 +51,35 @@ from repro.core import (Actor, BufferView, KernelBatch, MemPolicy,
                         UnifiedMemory, coalesce_runs, make_policy,
                         system_policy)
 from repro.models.layout import HeadLayout
+
+
+def _rows(pool, pids, slots):
+    """Index of every (token, head) row of a (P, N, page_size, D) pool.
+
+    Each index picks one contiguous D-row, so the TPU compiler scatters and
+    gathers in the pool's own layout; indexing (pids, :, slots) instead has
+    it relayout the whole pool around every scatter and gather."""
+    heads = jnp.arange(pool.shape[1])[None, :]
+    return pids[:, None], heads, slots[:, None]
+
+
+@functools.partial(jax.jit, donate_argnums=(0, 1))
+def kv_write(k_pool, v_pool, pids, slots, k, v):
+    """Scatter tokens' K and V into one layer's pools at (pids, :, slots).
+
+    k, v: (T, N, D), or with a unit axis the engine's programs leave on them,
+    (T, 1, N, D) from decode or (1, T, N, D) from prefill."""
+    N, D = k_pool.shape[1], k_pool.shape[3]
+    rows = _rows(k_pool, pids, slots)
+    return (k_pool.at[rows].set(k.reshape(-1, N, D)),
+            v_pool.at[rows].set(v.reshape(-1, N, D)))
+
+
+@jax.jit
+def kv_gather(k_pool, v_pool, pids, slots):
+    """One layer's K and V at (pids, :, slots): (T, N, D) each."""
+    rows = _rows(k_pool, pids, slots)
+    return k_pool[rows], v_pool[rows]
 
 
 class PagedKVCache:
@@ -78,6 +117,11 @@ class PagedKVCache:
         self.lengths = np.zeros((max_seqs,), np.int32)
         self.active = np.zeros((max_seqs,), bool)
         self._free: List[int] = list(range(self.num_pages - 1, 0, -1))  # 0 = null
+        # device copies of the last (pids, slots) written and gathered, keyed
+        # on their host bytes, and how often a pool call had to upload them
+        self._dev_idx: Dict[str, Tuple[bytes, object]] = {}
+        self.kv_pool_calls = 0
+        self.kv_index_uploads = 0
 
         self.um = um
         self.page_bytes = self.page_bytes_for(cfg, layout, page_size, dtype)
@@ -169,17 +213,36 @@ class PagedKVCache:
         assert (pids != 0).all(), "write into unallocated page"
         return pids, pos % self.page_size
 
+    def _device_idx(self, kind: str, pids, slots):
+        """(pids, slots) on the device, uploaded only where they differ from
+        the last ones of this ``kind``. Keyed on the indices themselves, not
+        on the sequence and positions: a released and reused sid maps the
+        same positions to other pages."""
+        self.kv_pool_calls += 1
+        pids = np.asarray(pids, np.int32)
+        slots = np.asarray(slots, np.int32)
+        key = pids.tobytes() + slots.tobytes()
+        hit = self._dev_idx.get(kind)
+        if hit is None or hit[0] != key:
+            self.kv_index_uploads += 1
+            hit = self._dev_idx[kind] = (key, jax.device_put((pids, slots)))
+        return hit[1]
+
+    def _write(self, layer: int, pids, slots, k, v) -> None:
+        self.k_pools[layer], self.v_pools[layer] = kv_write(
+            self.k_pools[layer], self.v_pools[layer],
+            *self._device_idx("write", pids, slots), k, v)
+
     # ------------------------------------------------------------- writes
     def write_at(self, sid: int, layer: int, k, v, start: int) -> None:
         """Scatter S tokens' KV at positions [start, start+S) of sequence sid.
 
-        k, v: (S, N, D). One fancy-index scatter per pool — every page of the
-        chunk lands at once, and the update covers exactly S slots (a partial
-        tail page is never zero-padded)."""
+        k, v: (S, N, D) or (1, S, N, D). One scatter per pool in one jitted
+        program — every page of the chunk lands at once, and the update
+        covers exactly S slots (a partial tail page is never zero-padded)."""
         with TraceAnnotation("serve.kv_write"):
-            pids, slots = self._flat_idx(sid, start, k.shape[0])
-            self.k_pools[layer] = self.k_pools[layer].at[pids, :, slots].set(k)
-            self.v_pools[layer] = self.v_pools[layer].at[pids, :, slots].set(v)
+            pids, slots = self._flat_idx(sid, start, k.shape[-3])
+            self._write(layer, pids, slots, k, v)
 
     def write_prefill(self, sid: int, layer: int, k, v) -> None:
         """k, v: (S, N, D) for one sequence; fills positions [0, S)."""
@@ -194,15 +257,14 @@ class PagedKVCache:
         self._touch(sid)
 
     def write_token(self, sid_list, layer: int, k, v, pos_list) -> None:
-        """k, v: (B, N, D) new-token KV for sequences sid_list at pos_list."""
+        """k, v: (B, N, D) or (B, 1, N, D) new-token KV for sequences
+        sid_list at pos_list."""
         with TraceAnnotation("serve.kv_write"):
             sids = np.asarray(sid_list)
             pos = np.asarray(pos_list)
             pids = self.page_table[sids, pos // self.page_size]
             assert (pids != 0).all(), "decode write into unallocated page"
-            slots = pos % self.page_size
-            self.k_pools[layer] = self.k_pools[layer].at[pids, :, slots].set(k)
-            self.v_pools[layer] = self.v_pools[layer].at[pids, :, slots].set(v)
+            self._write(layer, pids, pos % self.page_size, k, v)
 
     def commit_token(self, sid_list, pos_list) -> None:
         # lengths first, then one batched engine step over every decoded
@@ -227,8 +289,8 @@ class PagedKVCache:
         """Gather positions [0, length) of sequence sid -> (length, N, D) pair."""
         with TraceAnnotation("serve.kv_gather"):
             pids, slots = self._flat_idx(sid, 0, length)
-            return (self.k_pools[layer][pids, :, slots],
-                    self.v_pools[layer][pids, :, slots])
+            return kv_gather(self.k_pools[layer], self.v_pools[layer],
+                             *self._device_idx("gather", pids, slots))
 
     # ------------------------------------------------------------- swap
     def swap_out(self, sid: int) -> Dict[str, object]:
@@ -248,8 +310,7 @@ class PagedKVCache:
         L = int(saved["len"])
         self.alloc_range(sid, 0, L)
         for layer in range(self.cfg.num_layers):
-            self.write_at(sid, layer, jnp.asarray(saved["k"][layer]),
-                          jnp.asarray(saved["v"][layer]), 0)
+            self.write_at(sid, layer, saved["k"][layer], saved["v"][layer], 0)
         self.lengths[sid] = L
         return sid
 

@@ -12,6 +12,7 @@ from repro.configs import get_config
 from repro.core import UnifiedMemory
 from repro.models import init_params
 from repro.serve import ServeEngine
+from repro.serve import paged
 
 PROMPTS = [np.arange(2, 42), np.arange(5, 15), np.arange(7, 30)]
 NEW = 5
@@ -99,6 +100,8 @@ def test_programs_have_names_of_their_own(model):
     q, k, v = eng._qkv(p, x, pos)
     kpos = np.arange(4, dtype=np.int32)
     o = jax.numpy.zeros((1, 1, eng.layout.n_q_eff, cfg.head_dim))
+    pools = eng.cache.k_pools[0], eng.cache.v_pools[0]
+    pids, slots = np.ones(4, np.int32), pos
     lowered = {
         "embed": eng._embed.lower(params, np.zeros((1, 4), np.int32), pos),
         "layer_qkv": eng._qkv.lower(p, x, pos),
@@ -106,6 +109,8 @@ def test_programs_have_names_of_their_own(model):
             p, x, q, k[0], v[0], pos, kpos),
         "layer_rest": eng._decode_rest.lower(p, x[:, :1], o),
         "greedy_next": eng._greedy_next.lower(params, x),
+        "kv_write": paged.kv_write.lower(*pools, pids, slots, k, v),
+        "kv_gather": paged.kv_gather.lower(*pools, pids, slots),
     }
     for name, low in lowered.items():
         head = low.as_text().splitlines()[0]

@@ -1,4 +1,5 @@
-"""The Pallas kernels compile for a TPU v5e at the widths they run at.
+"""The Pallas kernels compile for a TPU v5e at the widths they run at, and
+the KV pool's programs compile to in-place updates of the pool.
 
 Nothing runs: each test lowers a kernel for a described (not attached) v5e
 chip and compiles it with the TPU compiler, which refuses what interpret
@@ -16,6 +17,7 @@ from jax.sharding import SingleDeviceSharding
 from repro.kernels.paged_attention import paged_attention
 from repro.kernels.qv_gate import apply_two_qubit_gate
 from repro.kernels.stencil5 import stencil5
+from repro.serve import paged
 
 
 @pytest.fixture(scope="module")
@@ -72,3 +74,21 @@ def test_qv_gate_compiles_at_qsim_fig3(one_chip):
     gate = _sds((4, 4), jnp.complex64, one_chip)
     _assert_kernel(apply_two_qubit_gate.lower(
         state, gate, 3, 11, n, interpret=False).compile())
+
+
+# yi-6b's f32 pool (16 seqs x 1,024 tokens in pages of 64, 4 KV heads of
+# 128); T tokens: a decode batch, a prefill chunk, a 3,584-token prefix
+@pytest.mark.parametrize("program,T", [("kv_write", 16), ("kv_write", 128),
+                                       ("kv_gather", 3584)])
+def test_kv_pool_programs_leave_the_pool_in_place(one_chip, program, T):
+    pool = _sds((257, 4, 64, 128), jnp.float32, one_chip)
+    idx = _sds((T,), jnp.int32, one_chip)
+    args = [pool, pool, idx, idx]
+    if program == "kv_write":
+        args += [_sds((T, 1, 4, 128), jnp.bfloat16, one_chip)] * 2
+    hlo = getattr(paged, program).lower(*args).compile().as_text()
+    # no copy of a whole pool (a relayout around the scatter or gather)
+    assert "f32[257,4,64,128]" not in "".join(
+        line for line in hlo.splitlines() if " copy(" in line)
+    if program == "kv_write":  # donated: the pools come back in place
+        assert "input_output_alias={ {0}: (0, {}" in hlo

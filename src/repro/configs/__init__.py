@@ -14,6 +14,7 @@ from repro.configs.base import (  # noqa: F401
 from repro.configs import (  # noqa: F401
     chameleon_34b,
     granite_moe_3b_a800m,
+    mellum2_12b_a2_5b,
     musicgen_medium,
     olmoe_1b_7b,
     qwen2_5_32b,

@@ -52,6 +52,22 @@ SHAPES: Dict[str, RunShape] = {
 
 
 @dataclass(frozen=True)
+class Yarn:
+    """YaRN scaling of RoPE (arXiv:2309.00071), with the keys and defaults
+    of transformers' ``rope_type: yarn``: frequencies between the correction
+    dims of ``beta_fast`` and ``beta_slow`` rotations over
+    ``original_max_position_embeddings`` blend from extrapolated to
+    interpolated (divided by ``factor``); cos and sin are multiplied by
+    ``attention_factor``."""
+
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float = 0.0  # 0: 0.1 ln(factor) + 1
+
+
+@dataclass(frozen=True)
 class ArchConfig:
     name: str
     family: str  # dense | vlm | audio | hybrid | moe | ssm
@@ -82,6 +98,9 @@ class ArchConfig:
     # hybrid (recurrentgemma): cycle of layer kinds; empty => [mixer]*L
     layer_pattern: Tuple[str, ...] = ()
     local_window: int = 0  # sliding-window size for 'local' attention layers
+    # YaRN RoPE on the full-attention ('attention') layers; 'local' layers
+    # keep plain RoPE. None: plain RoPE on every layer.
+    global_yarn: Optional[Yarn] = None
     lru_width: int = 0  # RG-LRU state width
     conv_width: int = 4  # temporal conv width (hybrid)
 
@@ -112,6 +131,10 @@ class ArchConfig:
     def sub_quadratic(self) -> bool:
         """True if the arch can run 500k-token decode (SSM / hybrid-local)."""
         return self.mixer in ("rwkv6", "rglru_hybrid")
+
+    def yarn_for(self, kind: str) -> Optional[Yarn]:
+        """The RoPE scaling of a layer of ``kind`` (None: plain RoPE)."""
+        return self.global_yarn if kind == "attention" else None
 
     def layer_kinds(self) -> List[str]:
         """Per-layer mixer kind, length num_layers."""
@@ -178,7 +201,7 @@ class ArchConfig:
         """Same-family tiny config for CPU smoke tests."""
         kw: Dict[str, Any] = dict(
             name=self.name + "-smoke",
-            num_layers=min(self.num_layers, 2 if not self.layer_pattern else 3),
+            num_layers=min(self.num_layers, len(self.layer_pattern) or 2),
             d_model=64,
             d_ff=128,
             vocab_size=256,
@@ -188,7 +211,9 @@ class ArchConfig:
             if self.num_kv_heads == self.num_heads:
                 kw.update(num_kv_heads=4)
         if self.mixer == "rglru_hybrid":
-            kw.update(lru_width=64, local_window=16)
+            kw.update(lru_width=64)
+        if self.local_window:
+            kw.update(local_window=16)
         if self.is_moe:
             kw.update(num_experts=8, top_k=2)
         if self.mixer == "rwkv6":

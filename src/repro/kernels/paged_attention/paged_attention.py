@@ -10,6 +10,12 @@ Head-major keeps that block's last two dims (PS, D) whole, as Mosaic's
 by the compiler. Pages past a sequence's length are skipped (no DMA-compute
 on dead pages).
 
+A static ``window`` > 0 is sliding-window attention: the new token at
+position ``length - 1`` attends keys ``kpos > length - 1 - window``. Pages
+wholly before the window are skipped like pages past the length: no compute,
+and their index map repeats the window's first page, so no DMA either.
+``window=0`` traces the unwindowed kernel unchanged.
+
 Grid: (B, Hkv, NP) — page dim innermost, online softmax in VMEM scratch.
 """
 from __future__ import annotations
@@ -25,8 +31,13 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.kernels.common import NEG_INF, resolve_interpret, tpu_compiler_params
 
 
+def _first_page(length, window: int, page_size: int):
+    """The first page holding a key inside the window of a sequence."""
+    return jnp.maximum(length - window, 0) // page_size
+
+
 def _kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
-            *, page_size: int, group: int):
+            *, page_size: int, group: int, window: int):
     b = pl.program_id(0)
     j = pl.program_id(2)
 
@@ -38,6 +49,8 @@ def _kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
 
     length = len_ref[b]
     live = j * page_size < length
+    if window:
+        live &= j >= _first_page(length, window, page_size)
 
     @pl.when(live)
     def _compute():
@@ -48,7 +61,10 @@ def _kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
                                 preferred_element_type=jnp.float32)
         s = s * (1.0 / math.sqrt(q.shape[-1]))
         kpos = j * page_size + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(kpos < length, s, NEG_INF)
+        ok = kpos < length
+        if window:
+            ok &= kpos >= length - window
+        s = jnp.where(ok, s, NEG_INF)
         m_prev = m_ref[:, :1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
@@ -69,15 +85,22 @@ def _kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
 
 
 def paged_attention_fwd(q, k_pool, v_pool, page_table, lengths, *,
-                        interpret: bool | None = None):
-    """q: (B,H,D); pools: (P,Hkv,PS,D); page_table: (B,NP); lengths: (B,)."""
+                        window: int = 0, interpret: bool | None = None):
+    """q: (B,H,D); pools: (P,Hkv,PS,D); page_table: (B,NP); lengths: (B,);
+    ``window``: keys attended from the end (0: all)."""
     B, H, D = q.shape
     P, Hkv, PS, _ = k_pool.shape
     NP = page_table.shape[1]
     assert H % Hkv == 0
     group = H // Hkv
     grid = (B, Hkv, NP)
-    kernel = functools.partial(_kernel, page_size=PS, group=group)
+    kernel = functools.partial(_kernel, page_size=PS, group=group,
+                               window=window)
+
+    def kv_index(b, h, j, pt, ln):
+        if window:
+            j = jnp.maximum(j, _first_page(ln[b], window, PS))
+        return pt[b, j], h, 0, 0
 
     # q viewed as (B, Hkv, group, D) so each grid step reads one kv-group
     q4 = q.reshape(B, Hkv, group, D)
@@ -87,8 +110,8 @@ def paged_attention_fwd(q, k_pool, v_pool, page_table, lengths, *,
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, 1, group, D), lambda b, h, j, pt, ln: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, PS, D), lambda b, h, j, pt, ln: (pt[b, j], h, 0, 0)),
-            pl.BlockSpec((1, 1, PS, D), lambda b, h, j, pt, ln: (pt[b, j], h, 0, 0)),
+            pl.BlockSpec((1, 1, PS, D), kv_index),
+            pl.BlockSpec((1, 1, PS, D), kv_index),
         ],
         out_specs=pl.BlockSpec((1, 1, group, D), lambda b, h, j, pt, ln: (b, h, 0, 0)),
         scratch_shapes=[

@@ -7,9 +7,10 @@ import jax.numpy as jnp
 from repro.kernels.common import NEG_INF
 
 
-def paged_attention_ref(q, k_pool, v_pool, page_table, lengths):
+def paged_attention_ref(q, k_pool, v_pool, page_table, lengths, window: int = 0):
     """q: (B,H,D); pools: (P, Hkv, PS, D); page_table: (B, NP) int32;
-    lengths: (B,) tokens valid per sequence. Returns (B,H,D).
+    lengths: (B,) tokens valid per sequence; window: only the last
+    ``window`` of them are attended (0: all). Returns (B,H,D).
 
     Gathers each sequence's pages then runs masked decode attention (GQA
     block mapping H = Hkv * group).
@@ -28,6 +29,8 @@ def paged_attention_ref(q, k_pool, v_pool, page_table, lengths):
     logits = jnp.einsum("bngd,bnkd->bngk", qf, k) / jnp.sqrt(float(D))
     pos = jnp.arange(NP * PS)[None, :]
     ok = pos < lengths[:, None]
+    if window:
+        ok &= pos >= lengths[:, None] - window
     logits = jnp.where(ok[:, None, None, :], logits, NEG_INF)
     probs = jax.nn.softmax(logits, axis=-1)
     out = jnp.einsum("bngk,bnkd->bngd", probs, v)

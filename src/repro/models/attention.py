@@ -50,8 +50,9 @@ def attn_init(cfg, layout: HeadLayout, key, dtype) -> Dict[str, Any]:
     return p
 
 
-def _project_qkv(cfg, p, x, layout: HeadLayout, positions):
-    """x: (B,S,d) -> q (B,S,N,P,D), k,v (B,S,N,D); RoPE applied."""
+def _project_qkv(cfg, p, x, layout: HeadLayout, positions, yarn=None):
+    """x: (B,S,d) -> q (B,S,N,P,D), k,v (B,S,N,D); RoPE (``yarn``-scaled
+    where given) applied."""
     B, S, _ = x.shape
     N, P, D = layout.n_kv_eff, layout.p, cfg.head_dim
     q = jnp.einsum("bsd,dhe->bshe", x, p["wq"], preferred_element_type=jnp.float32)
@@ -64,8 +65,8 @@ def _project_qkv(cfg, p, x, layout: HeadLayout, positions):
         q = head_rmsnorm(q, p["q_norm"])
         k = head_rmsnorm(k, p["k_norm"])
     if cfg.pos_emb == "rope":
-        q = rope_apply(q, positions, cfg.rope_theta)
-        k = rope_apply(k, positions, cfg.rope_theta)
+        q = rope_apply(q, positions, cfg.rope_theta, yarn)
+        k = rope_apply(k, positions, cfg.rope_theta, yarn)
     q = q.reshape(B, S, N, P, D)
     return q, k, v
 
@@ -130,11 +131,11 @@ def _causal_bias(qpos, kpos, window: int):
 
 
 def attn_apply(cfg, p, x, layout: HeadLayout, policy: RunPolicy, *, window: int = 0,
-               positions=None) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+               positions=None, yarn=None) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     B, S, _ = x.shape
     if positions is None:
         positions = jnp.arange(S, dtype=jnp.int32)
-    q, k, v = _project_qkv(cfg, p, x, layout, positions)
+    q, k, v = _project_qkv(cfg, p, x, layout, positions, yarn)
     qb = policy.attn_q_block
     if qb and S > qb:
         o = _blocked_causal(q, k, v, qb, policy.attn_kv_block or qb, window)
@@ -216,7 +217,7 @@ def _quant_heads(t):
 
 
 def attn_decode(cfg, p, x, layout: HeadLayout, policy: RunPolicy, pos, cache,
-                *, window: int = 0) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+                *, window: int = 0, yarn=None) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """x: (B,1,d); pos: (B,) absolute position of the new token.
 
     cache: {'k','v'}: (B, S, N, D) dense, or (B, W, N, D) ring when window>0.
@@ -224,7 +225,7 @@ def attn_decode(cfg, p, x, layout: HeadLayout, policy: RunPolicy, pos, cache,
     memory-term lever — halves HBM bytes per step).
     """
     B = x.shape[0]
-    q, k_new, v_new = _project_qkv(cfg, p, x, layout, pos[:, None])
+    q, k_new, v_new = _project_qkv(cfg, p, x, layout, pos[:, None], yarn)
     quant = "ks" in cache
     if quant:
         k_w, ks_w = _quant_heads(k_new)

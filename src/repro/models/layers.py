@@ -107,14 +107,44 @@ def norm_init(kind: str, d: int, dtype):
 # ---------------------------------------------------------------------------
 
 
-def rope_apply(x, positions, theta: float):
-    """x: (..., S, H, D); positions broadcastable to (..., S)."""
+def yarn_inv_freq(yarn, head_dim: int, theta: float):
+    """YaRN's inverse frequencies (head_dim // 2,) and its attention factor,
+    as transformers' ``_compute_yarn_parameters`` computes them (truncated
+    correction range, linear ramp), in float64 on the host."""
+    half = head_dim // 2
+    pos_freqs = theta ** (np.arange(0, head_dim, 2, dtype=np.float64) / head_dim)
+    extrapolation, interpolation = 1.0 / pos_freqs, 1.0 / (yarn.factor * pos_freqs)
+
+    def correction_dim(rotations):
+        return (head_dim * np.log(yarn.original_max_position_embeddings
+                                  / (rotations * 2 * np.pi))
+                / (2 * np.log(theta)))
+
+    low = max(np.floor(correction_dim(yarn.beta_fast)), 0)
+    high = min(np.ceil(correction_dim(yarn.beta_slow)), head_dim - 1)
+    if low == high:
+        high += 0.001  # as transformers: no singular ramp
+    ramp = np.clip((np.arange(half, dtype=np.float64) - low) / (high - low), 0, 1)
+    inv_freq = interpolation * ramp + extrapolation * (1 - ramp)
+    factor = yarn.attention_factor or 0.1 * np.log(yarn.factor) + 1.0
+    return inv_freq, float(factor)
+
+
+def rope_apply(x, positions, theta: float, yarn=None):
+    """x: (..., S, H, D); positions broadcastable to (..., S). ``yarn``
+    (configs.base.Yarn) scales the frequencies and cos and sin."""
     d = x.shape[-1]
     half = d // 2
-    freq = (1.0 / theta) ** (jnp.arange(half, dtype=jnp.float32) / half)
+    if yarn is None:
+        freq = (1.0 / theta) ** (jnp.arange(half, dtype=jnp.float32) / half)
+    else:
+        inv_freq, mscale = yarn_inv_freq(yarn, d, theta)
+        freq = jnp.asarray(inv_freq, jnp.float32)
     ang = positions.astype(jnp.float32)[..., None] * freq  # (..., S, half)
     cos = jnp.cos(ang)[..., None, :]  # (..., S, 1, half)
     sin = jnp.sin(ang)[..., None, :]
+    if yarn is not None:
+        cos, sin = cos * mscale, sin * mscale
     xf = x.astype(jnp.float32)
     x1, x2 = xf[..., :half], xf[..., half:]
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)

@@ -1,9 +1,16 @@
-"""Token-choice top-k MoE with capacity-bounded dense dispatch (GShard-style).
+"""Token-choice top-k MoE: a dropless layer for serving, and capacity-bounded
+dispatch (GShard-style dense, or sorted) for the training and dry-run paths.
 
 Experts are padded to a multiple of the model axis (granite: 40 -> 48) with
 -inf router logits on pads — exact, pads are never routed to. Expert weights
 shard over the model axis (expert parallelism); the dispatch/combine einsums
 lower to all-to-all-like collectives under GSPMD.
+
+``moe_apply_dropless`` sorts the (token, expert) rows by expert and runs each
+projection as one grouped matmul (``jax.lax.ragged_dot``): every token reaches
+all of its top-k experts, whatever its batch-mates route, so a served
+request's output does not depend on what it is batched with. On the TPU the
+grouped matmuls are the ``ragged-dot`` operations of the device trace.
 """
 from __future__ import annotations
 
@@ -41,6 +48,43 @@ def moe_init(cfg, key, dtype, tp: int) -> Dict[str, Any]:
         for k in ("w_gate", "w_up", "w_down"):
             p[k] = jnp.pad(p[k], ((0, pad), (0, 0), (0, 0)))
     return p
+
+
+def _route(cfg, router, xt):
+    """Softmax over the real experts in float32, top-k, renormalised:
+    (gates (T, K) f32, expert ids (T, K))."""
+    logits = (xt @ router.astype(jnp.float32)).astype(jnp.float32)
+    if router.shape[-1] != cfg.num_experts:
+        pad = jnp.arange(router.shape[-1]) >= cfg.num_experts
+        logits = jnp.where(pad[None, :], NEG_INF, logits)
+    gates, idx = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), cfg.top_k)
+    return gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9), idx
+
+
+def moe_apply_dropless(cfg, p, x) -> Tuple[jax.Array, jax.Array]:
+    """x: (B,S,d) -> (y, experts that received at least one row, int32).
+
+    The T*K (token, expert) rows are sorted by expert, and each projection
+    is one grouped matmul over the sorted rows; no row is dropped. Each
+    token's K outputs are combined with its gates in float32."""
+    B, S, d = x.shape
+    K = cfg.top_k
+    xt = x.reshape(B * S, d)
+    gates, idx = _route(cfg, p["router"], xt)
+    flat = idx.reshape(-1)  # row r is token r // K, slot r % K
+    order = jnp.argsort(flat, stable=True)
+    sizes = jnp.bincount(flat, length=p["router"].shape[-1]).astype(jnp.int32)
+    rows = xt[order // K]
+
+    def grouped(a, w):
+        return jax.lax.ragged_dot(a, w, sizes, preferred_element_type=jnp.float32)
+
+    h = (jax.nn.silu(grouped(rows, p["w_gate"]))
+         * grouped(rows, p["w_up"])).astype(x.dtype)
+    ye = grouped(h, p["w_down"])  # (T*K, d) f32, sorted by expert
+    back = jnp.zeros_like(order).at[order].set(jnp.arange(order.shape[0]))
+    y = (gates[:, :, None] * ye[back].reshape(B * S, K, d)).sum(axis=1)
+    return y.astype(x.dtype).reshape(B, S, d), jnp.sum(sizes > 0, dtype=jnp.int32)
 
 
 def moe_apply(cfg, p, x, policy: RunPolicy, tp: int = 1) -> Tuple[jax.Array, jax.Array]:

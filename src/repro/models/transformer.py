@@ -94,7 +94,8 @@ def _block_apply(cfg, kind: str, p, x, policy: RunPolicy, layout: Optional[HeadL
     if kind in ("attention", "local"):
         window = cfg.local_window if kind == "local" else 0
         mixed, _ = attn.attn_apply(cfg, p["mixer"], h, layout, policy,
-                                   window=window, positions=positions)
+                                   window=window, positions=positions,
+                                   yarn=cfg.yarn_for(kind))
     elif kind == "rglru":
         mixed = rglru_mod.rglru_apply(cfg, p["mixer"], h, policy)
     elif kind == "rwkv6":
@@ -260,7 +261,8 @@ def prefill(cfg: ArchConfig, params, tokens, policy: RunPolicy
             h0 = apply_norm(cfg.norm, h, lp["norm1"])
             if kinds[0] == "attention":
                 mixed, c = attn.attn_apply(cfg, lp["mixer"], h0, layout, policy,
-                                           positions=positions)
+                                           positions=positions,
+                                           yarn=cfg.yarn_for(kinds[0]))
             else:
                 mixed, ac = rwkv_mod.rwkv_att_apply(cfg, lp["mixer"], h0, policy,
                                                     return_cache=True)
@@ -288,7 +290,8 @@ def prefill(cfg: ArchConfig, params, tokens, policy: RunPolicy
         if kind in ("attention", "local"):
             window = cfg.local_window if kind == "local" else 0
             mixed, kv = attn.attn_apply(cfg, p["mixer"], h, layout, policy,
-                                        window=window, positions=positions)
+                                        window=window, positions=positions,
+                                        yarn=cfg.yarn_for(kind))
             if kind == "local" and S > cfg.local_window:
                 W = cfg.local_window
                 ring_k = jnp.roll(kv["k"][:, S - W:], S % W, axis=1)
@@ -337,7 +340,7 @@ def decode_step(cfg: ArchConfig, params, tokens, pos, cache, policy: RunPolicy
             h0 = apply_norm(cfg.norm, h, lp["norm1"])
             if kinds[0] == "attention":
                 mixed, nc = attn.attn_decode(cfg, lp["mixer"], h0, layout, policy,
-                                             pos, c)
+                                             pos, c, yarn=cfg.yarn_for(kinds[0]))
             else:
                 mixed, ac = rwkv_mod.rwkv_att_apply(cfg, lp["mixer"], h0, policy,
                                                     x_prev=c["xa"], s0=c["s"],
@@ -369,7 +372,7 @@ def decode_step(cfg: ArchConfig, params, tokens, pos, cache, policy: RunPolicy
         if kind in ("attention", "local"):
             window = cfg.local_window if kind == "local" else 0
             mixed, nc = attn.attn_decode(cfg, p["mixer"], h, layout, policy, pos, c,
-                                         window=window)
+                                         window=window, yarn=cfg.yarn_for(kind))
         elif kind == "rglru":
             mixed, nc = rglru_mod.rglru_decode(cfg, p["mixer"], h, policy, c)
         elif kind == "rwkv6":

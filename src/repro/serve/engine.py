@@ -36,6 +36,18 @@ them remotely — the paper's §7 graceful oversubscription, applied to
 serving. Attention-arch only (recurrent archs serve via the dense decode
 path in models/transformer.py — their state is O(1) in sequence length).
 
+**Layer kinds.** A config's layers are global attention (``attention``) or
+sliding-window attention (``local``, ``cfg.local_window``), in any pattern.
+Each kind has its own programs, built once: the kind fixes the window (in
+the prefill chunk's causal bias and as the paged kernel's static
+``window``) and the RoPE (``cfg.yarn_for``). A config with one kind builds
+the programs it always did. MoE configs run the dropless expert layer
+(``models/moe.moe_apply_dropless``) inside the layer's rest program; the
+engine counts the (token, expert) rows dispatched on the host
+(``EngineStats.expert_rows``) and the experts that received any row on the
+device (``EngineStats.experts_hit``: a device scalar each layer program
+adds to, read only when the stats are read).
+
 **Timing.** The engine keeps a modeled clock (:meth:`ServeEngine.now`:
 ``um.clock`` under a UnifiedMemory, the step index otherwise, plus any
 idle time skipped by :meth:`ServeEngine.advance_to`). Every request
@@ -58,10 +70,11 @@ prefill itself.
 about a microsecond while no trace is taken): ``serve.step`` (arg ``step``)
 around each step, ``serve.admit``, ``serve.prefill`` (``rid``, ``start``,
 ``end``) around a prefill chunk, ``serve.decode`` (``batch``) around a decode
-batch, and inside those ``serve.embed``, ``serve.qkv``, ``serve.attention``,
-``serve.layer_rest`` and ``serve.sample`` around the dispatch of each
-program. The KV pool adds ``serve.kv_write``/``kv_gather``/``kv_view``
-(serve/paged.py) and the memory model ``umem.*`` (core/umem.py). The jitted
+batch, and inside those ``serve.embed``, ``serve.qkv``, ``serve.attention``
+(arg ``window``), ``serve.layer_rest`` and ``serve.sample`` around the
+dispatch of each program. The KV pool adds
+``serve.kv_write``/``kv_gather``/``kv_view`` (serve/paged.py) and the
+memory model ``umem.*`` (core/umem.py). The jitted
 programs carry names of their own, so the device trace shows ``jit_embed``,
 ``jit_layer_qkv``, ``jit_prefill_layer_rest``, ``jit_layer_rest`` and
 ``jit_greedy_next``, and the KV pool's ``jit_kv_write`` and ``jit_kv_gather``.
@@ -99,26 +112,29 @@ def _embed(cfg, pol, params, toks, positions):
     return embed_in(cfg, params, toks, pol, positions)
 
 
-def _layer_qkv(cfg, lay, p, x, positions):
+def _layer_qkv(cfg, lay, yarn, p, x, positions):
     h = apply_norm(cfg.norm, x, p["norm1"])
-    return _project_qkv(cfg, p["mixer"], h, lay, positions)
+    return _project_qkv(cfg, p["mixer"], h, lay, positions, yarn)
 
 
-def _layer_rest(cfg, lay, pol, p, x, o):
-    """Residual add of the attention output ``o``, then the FFN block."""
+def _layer_rest(cfg, lay, pol, p, x, o, hits):
+    """Residual add of the attention output ``o``, then the FFN block; adds
+    to ``hits`` the experts that received a row (MoE)."""
     x = x + _out_proj(p["mixer"], o, lay)
     h2 = apply_norm(cfg.norm, x, p["norm2"])
     if cfg.is_moe:
-        y, _ = moe_mod.moe_apply(cfg, p["ffn"], h2, pol, tp=policy_tp(pol))
+        y, n = moe_mod.moe_apply_dropless(cfg, p["ffn"], h2)
+        hits = hits + n
     else:
         y = mlp_apply(cfg, p["ffn"], h2, pol)
-    return x + y
+    return x + y, hits
 
 
-def _prefill_layer_rest(cfg, lay, pol, p, x, q, k_full, v_full, positions,
-                        kpos):
-    o = _sdpa(q, k_full[None], v_full[None], _causal_bias(positions, kpos, 0))
-    return _layer_rest(cfg, lay, pol, p, x, o)
+def _prefill_layer_rest(cfg, lay, pol, window, p, x, q, k_full, v_full,
+                        positions, kpos, hits):
+    o = _sdpa(q, k_full[None], v_full[None],
+              _causal_bias(positions, kpos, window))
+    return _layer_rest(cfg, lay, pol, p, x, o, hits)
 
 
 def _greedy_next(cfg, pol, params, x):
@@ -182,6 +198,11 @@ class EngineStats:
     prefill_chunks: int = 0
     decode_batches: int = 0
     decode_tokens: int = 0
+    # MoE: (token, expert) rows dispatched, and experts that received at
+    # least one row, summed over layers and steps (on the device, read when
+    # ServeEngine.stats is)
+    expert_rows: int = 0
+    experts_hit: int = 0
     # fault-recovery accounting (zero in a fault-free run)
     node_losses: int = 0
     recovered_requests: int = 0
@@ -203,17 +224,23 @@ class ServeEngine:
                  tp_plan=None, fault_plan=None,
                  admit_backoff_steps: int = 2):
         assert cfg.mixer == "attention", "paged serving targets attention archs"
-        assert set(cfg.layer_kinds()) == {"attention"}, \
-            "the chunked-prefill path needs homogeneous global attention"
+        self.kinds = cfg.layer_kinds()
+        assert set(self.kinds) <= {"attention", "local"}, \
+            "paged serving runs global and sliding-window attention layers"
         self.cfg = cfg
         self.params = params
         self.policy = policy or RunPolicy()
         self.layout = kv_head_layout(cfg, policy_tp(self.policy))
         lay, pol = self.layout, self.policy
+        # per layer kind: the window (0: global) and the programs it fixes
+        self.window = {k: cfg.local_window if k == "local" else 0
+                       for k in set(self.kinds)}
         self._embed = _program("embed", _embed, cfg, pol)
-        self._qkv = _program("layer_qkv", _layer_qkv, cfg, lay)
-        self._prefill_rest = _program("prefill_layer_rest",
-                                      _prefill_layer_rest, cfg, lay, pol)
+        self._qkv = {k: _program("layer_qkv", _layer_qkv, cfg, lay,
+                                 cfg.yarn_for(k)) for k in self.window}
+        self._prefill_rest = {k: _program("prefill_layer_rest",
+                                          _prefill_layer_rest, cfg, lay, pol, w)
+                              for k, w in self.window.items()}
         self._decode_rest = _program("layer_rest", _layer_rest, cfg, lay, pol)
         self._greedy_next = _program("greedy_next", _greedy_next, cfg, pol)
         # tp_plan (e.g. repro.cluster.serve.ClusterTPPlan) maps sequences to
@@ -236,7 +263,8 @@ class ServeEngine:
         self.prefill_chunk = max(1, prefill_chunk)
         self.watermark_pages = watermark_pages
         self.admit_device_fraction = admit_device_fraction
-        self.stats = EngineStats()
+        self._stats = EngineStats()
+        self._experts_hit = jnp.zeros((), jnp.int32)  # the device's count
         self._needs_prefetch: List[Request] = []
         self._steps = 0
         self._idle_skipped = 0.0
@@ -346,7 +374,7 @@ class ServeEngine:
             if fresh and self.draining:
                 continue
             if fresh and self._hold_admit > 0:
-                self.stats.admission_retries += 1
+                self._stats.admission_retries += 1
                 continue
             if not self._admission_ok(req, running):
                 break
@@ -354,7 +382,7 @@ class ServeEngine:
             req.state = SeqState.PREFILL
             if req.admit_time is None:
                 req.admit_time = self.now()
-            self.stats.admitted += 1
+            self._stats.admitted += 1
             running.append(req)
             progressed += 1
         return progressed
@@ -390,7 +418,7 @@ class ServeEngine:
                 self.um.set_lane_degradation(None)
                 self._degrade_until = -1
             else:
-                self.stats.lane_degraded_steps += 1
+                self._stats.lane_degraded_steps += 1
         if self._spill_until >= 0 and self._steps >= self._spill_until:
             self.um.set_spill_failure(False)
             self._spill_until = -1
@@ -400,7 +428,7 @@ class ServeEngine:
         TP plan to the survivors, and replay every sequence whose KV pages
         are gone. Fresh admission backs off (doubling hold) so the shrunken
         pool re-stabilizes before taking new load."""
-        self.stats.node_losses += 1
+        self._stats.node_losses += 1
         lost = self.um.fail_node(node)
         if self.tp_plan is not None:
             self.tp_plan = self.tp_plan.without_node(node)
@@ -421,8 +449,8 @@ class ServeEngine:
         independent, so the replayed tokens come back bit-identical to the
         lost ones — the fault regression test pins the full stream against
         a fault-free run."""
-        self.stats.recovered_requests += 1
-        self.stats.replayed_tokens += len(req.generated) + req.prefill_pos
+        self._stats.recovered_requests += 1
+        self._stats.replayed_tokens += len(req.generated) + req.prefill_pos
         if req.sid >= 0:
             self.cache.release(req.sid)
             req.sid = -1
@@ -451,14 +479,14 @@ class ServeEngine:
                 # Fall back to dropping it and recomputing from the prompt
                 # — greedy decode replays bit-identically, so correctness
                 # survives at a recompute (not preemption) cost
-                self.stats.spill_failures += 1
+                self._stats.spill_failures += 1
                 self._replay(req)
                 return
         req.saved = self.cache.swap_out(req.sid)
         req.sid = -1
         req.state = SeqState.PREEMPTED
         req.preemptions += 1
-        self.stats.preempted += 1
+        self._stats.preempted += 1
 
     def _resume(self, req: Request) -> None:
         req.sid = self.cache.swap_in(req.saved)
@@ -466,7 +494,7 @@ class ServeEngine:
         # a sequence preempted mid-prefill picks its prompt back up
         req.state = (SeqState.DECODING if req.prefill_pos == len(req.prompt)
                      else SeqState.PREFILL)
-        self.stats.resumed += 1
+        self._stats.resumed += 1
         if self.um is not None:
             self._needs_prefetch.append(req)
 
@@ -522,22 +550,24 @@ class ServeEngine:
             toks = np.asarray(req.prompt[s:e], np.int32)[None, :]
             positions = np.arange(s, e, dtype=np.int32)
             kpos = np.arange(e, dtype=np.int32)
+            hits = self._experts_hit
             with TraceAnnotation("serve.embed"):
                 x = self._embed(self.params, toks, positions)
-            for i in range(self.cfg.num_layers):
+            for i, kind in enumerate(self.kinds):
                 p = self.params["layers"][i]
                 with TraceAnnotation("serve.qkv"):
-                    q, k_new, v_new = self._qkv(p, x, positions)
+                    q, k_new, v_new = self._qkv[kind](p, x, positions)
                 self.cache.write_at(req.sid, i, k_new, v_new, s)
                 k_full, v_full = self.cache.gather_kv(req.sid, i, e)
                 with TraceAnnotation("serve.layer_rest"):
-                    x = self._prefill_rest(p, x, q, k_full, v_full, positions,
-                                           kpos)
+                    x, hits = self._prefill_rest[kind](
+                        p, x, q, k_full, v_full, positions, kpos, hits)
+            self._count_experts(chunk, hits)
             req.prefill_pos = e
             self.cache.commit_prefill(req.sid, e)
             if self.tp_plan is not None:
                 self.tp_plan.on_prefill(self, chunk)
-            self.stats.prefill_chunks += 1
+            self._stats.prefill_chunks += 1
             if e == len(req.prompt):
                 with TraceAnnotation("serve.sample"):
                     req.generated.append(
@@ -593,32 +623,46 @@ class ServeEngine:
             positions = np.asarray(pos, np.int32)[:, None]
             pt, ln = self.cache.batch_view(sids)
 
+            hits = self._experts_hit
             with TraceAnnotation("serve.embed"):
                 x = self._embed(self.params, tokens, positions)
-            for i in range(cfg.num_layers):
+            for i, kind in enumerate(self.kinds):
                 p = self.params["layers"][i]
                 with TraceAnnotation("serve.qkv"):
-                    q, k_new, v_new = self._qkv(p, x, positions)
+                    q, k_new, v_new = self._qkv[kind](p, x, positions)
                 self.cache.write_token(sids, i, k_new, v_new, pos)
-                with TraceAnnotation("serve.attention"):
+                window = self.window[kind]
+                with TraceAnnotation("serve.attention", window=window):
                     qd = q.reshape(B, lay.n_q_eff, cfg.head_dim)
                     o = paged_attention(qd, self.cache.k_pools[i],
-                                        self.cache.v_pools[i], pt, ln + 1)
+                                        self.cache.v_pools[i], pt, ln + 1,
+                                        window=window)
                 with TraceAnnotation("serve.layer_rest"):
-                    x = self._decode_rest(p, x, o[:, None])
+                    x, hits = self._decode_rest(p, x, o[:, None], hits)
+            self._count_experts(B, hits)
             with TraceAnnotation("serve.sample"):
                 nxt = np.asarray(self._greedy_next(self.params, x))
             self.cache.commit_token(sids, pos)
             if self.tp_plan is not None:
                 self.tp_plan.on_decode(self, len(reqs))
-            self.stats.decode_batches += 1
-            self.stats.decode_tokens += len(reqs)
+            self._stats.decode_batches += 1
+            self._stats.decode_tokens += len(reqs)
             for r, t in zip(reqs, nxt):
                 r.generated.append(int(t))
                 total = len(r.prompt) + len(r.generated)
                 if (len(r.generated) >= r.max_new_tokens
                         or total >= self.max_len - 1):
                     self._finish(r)
+
+    @property
+    def stats(self) -> EngineStats:
+        """The counters, with the device's count of experts hit read in."""
+        self._stats.experts_hit = int(self._experts_hit)
+        return self._stats
+
+    def _count_experts(self, tokens: int, hits) -> None:
+        self._stats.expert_rows += tokens * self.cfg.top_k * self.cfg.num_layers
+        self._experts_hit = hits
 
     def _finish(self, req: Request) -> None:
         req.state = SeqState.DONE
@@ -643,8 +687,8 @@ class ServeEngine:
         with TraceAnnotation("serve.step", step=self._steps):
             if self.fault_plan is not None:
                 self._apply_faults()
-            pre0 = self.stats.preempted
-            rec0 = self.stats.recovered_requests
+            pre0 = self._stats.preempted
+            rec0 = self._stats.recovered_requests
             progress = 0
             if self._hold_admit > 0:
                 # the post-fault backoff window ticking down IS forward
@@ -667,8 +711,8 @@ class ServeEngine:
             # so it counts as progress (a genuine deadlock preempts nothing
             # either); likewise a fault replay requeues real work for the
             # next step
-            progress += self.stats.preempted - pre0
-            progress += self.stats.recovered_requests - rec0
+            progress += self._stats.preempted - pre0
+            progress += self._stats.recovered_requests - rec0
             if self.um is not None:
                 self.um.sync()  # sync point: apply counter-driven delayed migrations
             self._steps += 1

@@ -54,6 +54,41 @@ def test_paged_attention(B, H, Hkv, D, P, PS, NP, dtype):
                                np.asarray(r, np.float32), atol=_tol(dtype))
 
 
+def _pool_case(B, H, Hkv, D, P, PS, NP, dtype):
+    ks = jax.random.split(jax.random.PRNGKey(5), 4)
+    q = jax.random.normal(ks[0], (B, H, D), dtype)
+    kp = jax.random.normal(ks[1], (P, Hkv, PS, D), dtype)
+    vp = jax.random.normal(ks[2], (P, Hkv, PS, D), dtype)
+    pt = jax.random.permutation(ks[3], P)[:B * NP].reshape(B, NP).astype(jnp.int32)
+    return q, kp, vp, pt
+
+
+# lengths inside the window, ending on and past page edges, and far past it
+@pytest.mark.parametrize("window", [16, 24, 40])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_paged_attention_window(window, dtype):
+    q, kp, vp, pt = _pool_case(4, 8, 2, 64, 32, 8, 8, dtype)
+    lengths = jnp.asarray([5, 16, 41, 64], jnp.int32)
+    o = paged_attention(q, kp, vp, pt, lengths, window=window, interpret=True)
+    r = paged_attention_ref(q, kp, vp, pt, lengths, window=window)
+    np.testing.assert_allclose(np.asarray(o, np.float32),
+                               np.asarray(r, np.float32), atol=_tol(dtype))
+    # the window binds: attending every key gives another result
+    full = paged_attention_ref(q, kp, vp, pt, lengths)
+    assert not np.allclose(np.asarray(full[2:], np.float32),
+                           np.asarray(r[2:], np.float32), atol=_tol(dtype))
+
+
+def test_paged_attention_window_zero_is_unwindowed():
+    """window=0 attends every key: bit for bit what a window no sequence
+    reaches gives (the pages before it are skipped, nothing else moves)."""
+    q, kp, vp, pt = _pool_case(3, 8, 2, 64, 32, 8, 8, jnp.bfloat16)
+    lengths = jnp.asarray([7, 33, 64], jnp.int32)
+    o0 = paged_attention(q, kp, vp, pt, lengths, interpret=True)
+    o_wide = paged_attention(q, kp, vp, pt, lengths, window=64, interpret=True)
+    assert np.array_equal(np.asarray(o0), np.asarray(o_wide))
+
+
 @pytest.mark.parametrize("n,q1,q2", [(10, 0, 1), (12, 3, 9), (12, 11, 2), (11, 7, 6)])
 def test_qv_gate(n, q1, q2):
     k1, k2 = jax.random.split(jax.random.PRNGKey(2))

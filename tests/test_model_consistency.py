@@ -15,7 +15,8 @@ from repro.models.layout import HeadLayout
 
 
 @pytest.mark.parametrize("arch", ["yi-6b", "qwen2.5-32b", "recurrentgemma-2b",
-                                  "rwkv6-1.6b", "olmoe-1b-7b", "musicgen-medium"])
+                                  "rwkv6-1.6b", "olmoe-1b-7b", "musicgen-medium",
+                                  "mellum2-12b-a2.5b"])
 def test_prefill_then_decode_matches_forward(arch):
     cfg = get_config(arch).reduced()
     params = init_params(cfg, jax.random.PRNGKey(0))
@@ -111,3 +112,34 @@ def test_int8_kv_cache_decode_close_to_fp():
         np.testing.assert_allclose(
             np.asarray(jax.nn.softmax(lg[:, 0])),
             np.asarray(jax.nn.softmax(full[:, i])), atol=0.05)
+
+
+def test_yarn_frequencies_worked_by_hand():
+    """Mellum2's full layers: theta 5e5, head 128, factor 16 over 8,192
+    positions, beta 32 / 1. The correction dims are
+    128 ln(8192 / (2 pi b)) / (2 ln 5e5): 18.08 for b = 32 (floor 18) and
+    34.98 for b = 1 (ceil 35). Below dim 18 a frequency keeps
+    theta^(-2i/128), from 35 on it is divided by 16, and between them the
+    ramp (i - 18) / 17 blends the two; cos and sin scale by
+    0.1 ln 16 + 1."""
+    from repro.models.layers import rope_apply, yarn_inv_freq
+
+    cfg = get_config("mellum2-12b-a2.5b")
+    inv, mscale = yarn_inv_freq(cfg.global_yarn, 128, cfg.rope_theta)
+    assert inv.shape == (64,) and inv[0] == 1.0
+    np.testing.assert_allclose(inv[10], 0.12868737343265052, rtol=1e-12)
+    np.testing.assert_allclose(inv[26], 0.0027043825167258227, rtol=1e-12)
+    np.testing.assert_allclose(inv[63], 1.5344629944572555e-07, rtol=1e-12)
+    np.testing.assert_allclose(inv[18], 500000.0 ** (-36 / 128), rtol=1e-12)
+    np.testing.assert_allclose(inv[35], 500000.0 ** (-70 / 128) / 16, rtol=1e-12)
+    assert mscale == 1.2772588722239782
+    # rope_apply: at position 1, dim 0 turns by 1 rad and the pair's norm
+    # grows by the attention factor; sliding layers keep plain RoPE
+    x = jnp.zeros((1, 1, 128)).at[0, 0, 0].set(1.0)
+    y = np.asarray(rope_apply(x, jnp.ones((1,), jnp.int32), cfg.rope_theta,
+                              cfg.global_yarn))[0, 0]
+    np.testing.assert_allclose([y[0], y[64]],
+                               [mscale * np.cos(1.0), mscale * np.sin(1.0)],
+                               rtol=1e-6)
+    assert cfg.yarn_for("local") is None
+    assert cfg.yarn_for("attention") is cfg.global_yarn

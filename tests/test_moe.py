@@ -92,3 +92,65 @@ def test_sorted_dispatch_matches_dense():
         yd, _ = moe_mod.moe_apply_dense(cfg, p, x, RunPolicy(moe_capacity_factor=cf))
         ys, _ = moe_mod.moe_apply_sorted(cfg, p, x, RunPolicy(moe_capacity_factor=cf))
         np.testing.assert_allclose(np.asarray(yd), np.asarray(ys), atol=2e-5)
+
+
+def _per_token(cfg, p, xt):
+    """Each token through its top-k experts, one at a time, in float32."""
+    logits = xt @ p["router"][:, :cfg.num_experts]
+    g, idx = jax.lax.top_k(jax.nn.softmax(logits, -1), cfg.top_k)
+    g = g / g.sum(-1, keepdims=True)
+    out = []
+    for t in range(xt.shape[0]):
+        acc = jnp.zeros((cfg.d_model,))
+        for s in range(cfg.top_k):
+            e = int(idx[t, s])
+            h = jax.nn.silu(xt[t] @ p["w_gate"][e]) * (xt[t] @ p["w_up"][e])
+            acc = acc + g[t, s] * (h @ p["w_down"][e])
+        out.append(acc)
+    return jnp.stack(out), len(set(np.asarray(idx).ravel().tolist()))
+
+
+@pytest.mark.parametrize("skew", [0.0, 8.0])
+def test_dropless_matches_per_token_loop(skew):
+    """Every token reaches all its top-k experts, also when most tokens
+    route to one expert (``skew``: a bias on expert 0's router column, past
+    any capacity the capacity paths would give it)."""
+    cfg = _cfg(n_experts=16, top_k=4)
+    p = moe_mod.moe_init(cfg, jax.random.PRNGKey(3), jnp.float32, tp=1)
+    x = jax.random.normal(jax.random.PRNGKey(4), (3, 11, cfg.d_model))
+    if skew:
+        p["router"] = p["router"].at[:, 0].add(skew * x.mean((0, 1)) /
+                                                jnp.sum(x.mean((0, 1)) ** 2))
+    y, hit = moe_mod.moe_apply_dropless(cfg, p, x)
+    ref, n_hit = _per_token(cfg, p, x.reshape(-1, cfg.d_model))
+    np.testing.assert_allclose(np.asarray(y.reshape(-1, cfg.d_model)),
+                               np.asarray(ref), atol=1e-5)
+    assert int(hit) == n_hit
+    if skew:  # the capacity path drops what the dropless one keeps
+        y_cap, _ = moe_mod.moe_apply(cfg, p, x, RunPolicy(), tp=1)
+        assert not np.allclose(np.asarray(y_cap), np.asarray(y), atol=1e-3)
+
+
+def test_dropless_never_routes_to_padded_experts():
+    cfg = _cfg(n_experts=6, top_k=2)
+    p8 = moe_mod.moe_init(cfg, jax.random.PRNGKey(0), jnp.float32, tp=8)
+    p1 = moe_mod.moe_init(cfg, jax.random.PRNGKey(0), jnp.float32, tp=1)
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, 16, cfg.d_model))
+    y8, hit8 = moe_mod.moe_apply_dropless(cfg, p8, x)
+    y1, hit1 = moe_mod.moe_apply_dropless(cfg, p1, x)
+    np.testing.assert_allclose(np.asarray(y8), np.asarray(y1), atol=1e-6)
+    assert int(hit8) == int(hit1) <= 6
+
+
+def test_dropless_output_is_independent_of_batch_mates():
+    """A token's output is the same alone as in any batch (bf16 weights)."""
+    cfg = _cfg(n_experts=16, top_k=4)
+    p = moe_mod.moe_init(cfg, jax.random.PRNGKey(5), jnp.bfloat16, tp=1)
+    x = jax.random.normal(jax.random.PRNGKey(6), (1, 24, cfg.d_model),
+                          jnp.bfloat16)
+    together, _ = moe_mod.moe_apply_dropless(cfg, p, x)
+    alone = [moe_mod.moe_apply_dropless(cfg, p, x[:, i:i + 1])[0]
+             for i in range(x.shape[1])]
+    np.testing.assert_array_equal(np.asarray(together, np.float32),
+                                  np.asarray(jnp.concatenate(alone, 1),
+                                             np.float32))

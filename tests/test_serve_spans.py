@@ -97,17 +97,19 @@ def test_programs_have_names_of_their_own(model):
     x = jax.numpy.zeros((1, 4, cfg.d_model), jax.numpy.float32)
     pos = np.arange(4, dtype=np.int32)
     p = params["layers"][0]
-    q, k, v = eng._qkv(p, x, pos)
+    qkv, prefill_rest = eng._qkv["attention"], eng._prefill_rest["attention"]
+    q, k, v = qkv(p, x, pos)
     kpos = np.arange(4, dtype=np.int32)
     o = jax.numpy.zeros((1, 1, eng.layout.n_q_eff, cfg.head_dim))
+    hits = jax.numpy.int32(0)
     pools = eng.cache.k_pools[0], eng.cache.v_pools[0]
     pids, slots = np.ones(4, np.int32), pos
     lowered = {
         "embed": eng._embed.lower(params, np.zeros((1, 4), np.int32), pos),
-        "layer_qkv": eng._qkv.lower(p, x, pos),
-        "prefill_layer_rest": eng._prefill_rest.lower(
-            p, x, q, k[0], v[0], pos, kpos),
-        "layer_rest": eng._decode_rest.lower(p, x[:, :1], o),
+        "layer_qkv": qkv.lower(p, x, pos),
+        "prefill_layer_rest": prefill_rest.lower(
+            p, x, q, k[0], v[0], pos, kpos, hits),
+        "layer_rest": eng._decode_rest.lower(p, x[:, :1], o, hits),
         "greedy_next": eng._greedy_next.lower(params, x),
         "kv_write": paged.kv_write.lower(*pools, pids, slots, k, v),
         "kv_gather": paged.kv_gather.lower(*pools, pids, slots),
@@ -133,3 +135,17 @@ def test_wall_stamps_order_and_modeled_stamps_stay(model):
     # no earlier than an earlier one
     starts = [eng.requests[rid].prefill_wall for rid in rids]
     assert starts == sorted(starts)
+
+
+def test_attention_spans_carry_each_layers_window(tmp_path):
+    """Sliding-window and full layers side by side: each decode layer's
+    ``serve.attention`` span names the window its kernel ran with."""
+    cfg = get_config("mellum2-12b-a2.5b").reduced()
+    eng = engine((cfg, init_params(cfg, jax.random.PRNGKey(0))))
+    _, spans = traced_spans(eng, tmp_path)
+    windows = [s[3]["window"] for s in sorted(spans, key=lambda s: s[1])
+               if s[0] == "serve.attention"]
+    per_step = [cfg.local_window if k == "local" else 0
+                for k in cfg.layer_kinds()]
+    assert windows == per_step * eng.stats.decode_batches
+    assert per_step == [16, 16, 16, 0]

@@ -8,6 +8,7 @@ topology is described inside a fixture, so only the worker that runs these
 tests loads the TPU library; where it cannot be described the tests skip.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -92,3 +93,41 @@ def test_kv_pool_programs_leave_the_pool_in_place(one_chip, program, T):
         line for line in hlo.splitlines() if " copy(" in line)
     if program == "kv_write":  # donated: the pools come back in place
         assert "input_output_alias={ {0}: (0, {}" in hlo
+
+
+# mellum2 decode: 32 q / 4 kv heads of 128, 8 x 4,096 tokens in pages of
+# 64, the sliding layers' 1,024-token window
+def test_windowed_paged_attention_compiles(one_chip):
+    B, PS, NP = 8, 64, 64
+    P = B * NP + 1
+    args = (_sds((B, 32, 128), jnp.bfloat16, one_chip),
+            _sds((P, 4, PS, 128), jnp.float32, one_chip),
+            _sds((P, 4, PS, 128), jnp.float32, one_chip),
+            _sds((B, NP), jnp.int32, one_chip),
+            _sds((B,), jnp.int32, one_chip))
+    _assert_kernel(paged_attention.lower(*args, window=1024,
+                                         interpret=False).compile())
+
+
+# mellum2's expert layer at published widths: 64 experts of 2,304 x 896,
+# top-8, for a decode batch of 8 and a 128-token prefill chunk
+@pytest.mark.parametrize("T", [8, 128])
+def test_dropless_experts_compile_to_grouped_matmuls(one_chip, T):
+    import dataclasses
+
+    from repro.configs import get_config
+    from repro.models import moe
+
+    cfg = dataclasses.replace(get_config("mellum2-12b-a2.5b"), num_layers=1)
+    d, f, E = cfg.d_model, cfg.d_ff, cfg.num_experts
+    p = {"router": _sds((d, E), jnp.bfloat16, one_chip),
+         "w_gate": _sds((E, d, f), jnp.bfloat16, one_chip),
+         "w_up": _sds((E, d, f), jnp.bfloat16, one_chip),
+         "w_down": _sds((E, f, d), jnp.bfloat16, one_chip)}
+    x = _sds((1, T, d), jnp.bfloat16, one_chip)
+    hlo = jax.jit(lambda p, x: moe.moe_apply_dropless(cfg, p, x)).lower(
+        p, x).compile().as_text()
+    # one grouped matmul a projection, under the operation name the device
+    # trace shows and bench/metrics/expert_ffn_roofline.py reads
+    assert len(re.findall(r"%ragged-dot-none[.\d]* = \S+ custom-call\(",
+                          hlo)) == 3

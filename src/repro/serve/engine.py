@@ -46,7 +46,8 @@ the programs it always did. MoE configs run the dropless expert layer
 engine counts the (token, expert) rows dispatched on the host
 (``EngineStats.expert_rows``) and the experts that received any row on the
 device (``EngineStats.experts_hit``: a device scalar each layer program
-adds to, read only when the stats are read).
+adds to, read only when the stats are read). A dense config's programs
+take and return no such scalar (``None``: no buffer).
 
 **Timing.** The engine keeps a modeled clock (:meth:`ServeEngine.now`:
 ``um.clock`` under a UnifiedMemory, the step index otherwise, plus any
@@ -65,19 +66,32 @@ is sampled. ``prefill_wall - arrival_wall`` is the wait for the admission
 gate and the shared prefill budget; ``first_token_wall - prefill_wall`` the
 prefill itself.
 
+**Programs.** A layer runs as three programs, in this order: the KV
+pool's ``jit_kv_write`` (serve/paged.py), then in decode the paged kernel
+(``jit_paged_attention``) and in prefill the pool's ``jit_kv_gather``, then
+one program that runs the layer's output projection, residual and FFN and,
+in the same program, the next layer's ``norm1`` and QKV projection
+(``jit_layer_rest_qkv``, ``jit_prefill_layer_rest_qkv``; the last layer's
+runs without it: ``jit_layer_rest``, ``jit_prefill_layer_rest``). The
+embedding and layer 0's QKV are one program (``jit_embed_qkv``), and
+``jit_greedy_next`` samples. A decode batch dispatches 3L+2 programs, a
+prefill chunk 3L+1 and one more where it samples
+(``EngineStats.programs``). The programs are composed, when they are
+traced, from the engine's attributes ``_embed``, ``_qkv[kind]``,
+``_decode_rest`` and ``_prefill_rest[kind]``; the paged kernel reads
+``window[kind]`` at each call.
+
 **Spans.** The engine's phases run inside host spans
 (``jax.profiler.TraceAnnotation``, on the profiler's clock, a no-op costing
 about a microsecond while no trace is taken): ``serve.step`` (arg ``step``)
 around each step, ``serve.admit``, ``serve.prefill`` (``rid``, ``start``,
 ``end``) around a prefill chunk, ``serve.decode`` (``batch``) around a decode
-batch, and inside those ``serve.embed``, ``serve.qkv``, ``serve.attention``
-(arg ``window``), ``serve.layer_rest`` and ``serve.sample`` around the
-dispatch of each program. The KV pool adds
-``serve.kv_write``/``kv_gather``/``kv_view`` (serve/paged.py) and the
-memory model ``umem.*`` (core/umem.py). The jitted
-programs carry names of their own, so the device trace shows ``jit_embed``,
-``jit_layer_qkv``, ``jit_prefill_layer_rest``, ``jit_layer_rest`` and
-``jit_greedy_next``, and the KV pool's ``jit_kv_write`` and ``jit_kv_gather``.
+batch, and inside those ``serve.embed`` (and in it ``serve.qkv``) around
+the program that embeds and projects layer 0's QKV, ``serve.attention``
+(arg ``window``) around the kernel, ``serve.layer_rest`` around each
+layer's folded program and ``serve.sample`` around the sampling. The KV
+pool adds ``serve.kv_write``/``kv_gather``/``kv_view`` (serve/paged.py) and
+the memory model ``umem.*`` (core/umem.py).
 """
 from __future__ import annotations
 
@@ -103,9 +117,10 @@ from repro.models.transformer import embed_in, logits_out, policy_tp
 from repro.serve.paged import PagedKVCache
 
 
-# One transformer layer runs as two jitted programs on either side of the KV
-# pool's jitted write and gather (serve/paged.py): one compile per shape,
-# where op-by-op dispatch compiled every op of the layer at every new shape.
+# The pieces of a layer, each its own jitted program where it is called
+# alone and inlined where the engine's folded programs call it (see the
+# module doc's "Programs"): one compile per shape, where op-by-op dispatch
+# compiled every op of the layer at every new shape.
 
 
 def _embed(cfg, pol, params, toks, positions):
@@ -198,6 +213,8 @@ class EngineStats:
     prefill_chunks: int = 0
     decode_batches: int = 0
     decode_tokens: int = 0
+    # programs the steps dispatched, the engine's own and the KV pool's
+    programs: int = 0
     # MoE: (token, expert) rows dispatched, and experts that received at
     # least one row, summed over layers and steps (on the device, read when
     # ServeEngine.stats is)
@@ -243,6 +260,20 @@ class ServeEngine:
                               for k, w in self.window.items()}
         self._decode_rest = _program("layer_rest", _layer_rest, cfg, lay, pol)
         self._greedy_next = _program("greedy_next", _greedy_next, cfg, pol)
+        # the programs the step dispatches, folded from the ones above: each
+        # layer's kind and the next layer's (None after the last)
+        self._next_kinds = list(zip(self.kinds, self.kinds[1:] + [None]))
+        first = self.kinds[0]
+        self._decode_head = _program("embed_qkv", self._head, first, True)
+        self._prefill_head = _program("embed_qkv", self._head, first, False)
+        self._decode_fold = {
+            n: _program("layer_rest_qkv" if n else "layer_rest",
+                        self._decode_layer, n)
+            for _, n in self._next_kinds}
+        self._prefill_fold = {
+            (k, n): _program("prefill_layer_rest_qkv" if n
+                             else "prefill_layer_rest", self._prefill_layer, k, n)
+            for k, n in self._next_kinds}
         # tp_plan (e.g. repro.cluster.serve.ClusterTPPlan) maps sequences to
         # serving superchips and charges per-token tensor-parallel collective
         # traffic; it only ADDS modeled charges and node pins, so generated
@@ -264,7 +295,9 @@ class ServeEngine:
         self.watermark_pages = watermark_pages
         self.admit_device_fraction = admit_device_fraction
         self._stats = EngineStats()
-        self._experts_hit = jnp.zeros((), jnp.int32)  # the device's count
+        # the device's count of experts hit (MoE); None threads no buffer
+        self._experts_hit = (jnp.zeros((), jnp.int32) if cfg.is_moe
+                             else None)
         self._needs_prefetch: List[Request] = []
         self._steps = 0
         self._idle_skipped = 0.0
@@ -287,6 +320,41 @@ class ServeEngine:
         self._backoff = self.admit_backoff_steps
         self._hold_admit = 0  # steps fresh admission stays held post-fault
         self.draining = False
+
+    # ------------------------------------------------------ folded programs
+    # Traced inside the programs built in __init__: the jitted pieces they
+    # call are inlined, read from the engine's attributes at trace time.
+    def _qkv_of(self, kind, p, x, positions, decode: bool):
+        """Layer ``kind``'s norm1 and QKV projection; in decode q comes out
+        in the paged kernel's (B, n_q_eff, D)."""
+        q, k, v = self._qkv[kind](p, x, positions)
+        if decode:
+            q = q.reshape(x.shape[0], self.layout.n_q_eff, self.cfg.head_dim)
+        return q, k, v
+
+    def _head(self, kind, decode, params, toks, positions):
+        """The embedding, then layer 0's QKV: (x, q, k, v)."""
+        x = self._embed(params, toks, positions)
+        return (x,) + self._qkv_of(kind, params["layers"][0], x, positions,
+                                   decode)
+
+    def _decode_layer(self, nxt, p, x, o, hits, p_next, positions):
+        """A decode layer after its attention output ``o`` (B, n_q_eff, D),
+        then layer ``nxt``'s QKV: (x, hits, q, k, v), or (x, hits)."""
+        x, hits = self._decode_rest(p, x, o[:, None], hits)
+        if nxt is None:
+            return x, hits
+        return (x, hits) + self._qkv_of(nxt, p_next, x, positions, True)
+
+    def _prefill_layer(self, kind, nxt, p, x, q, k_full, v_full, positions,
+                       kpos, hits, p_next):
+        """A prefill layer of ``kind`` from its QKV and the gathered prefix,
+        then layer ``nxt``'s QKV: (x, hits, q, k, v), or (x, hits)."""
+        x, hits = self._prefill_rest[kind](p, x, q, k_full, v_full,
+                                           positions, kpos, hits)
+        if nxt is None:
+            return x, hits
+        return (x, hits) + self._qkv_of(nxt, p_next, x, positions, False)
 
     # ----------------------------------------------------------------- clock
     def now(self) -> float:
@@ -548,21 +616,24 @@ class ServeEngine:
         with TraceAnnotation("serve.prefill", rid=req.rid, start=s, end=e):
             self.cache.alloc_range(req.sid, s, e)
             toks = np.asarray(req.prompt[s:e], np.int32)[None, :]
-            positions = np.arange(s, e, dtype=np.int32)
-            kpos = np.arange(e, dtype=np.int32)
-            hits = self._experts_hit
-            with TraceAnnotation("serve.embed"):
-                x = self._embed(self.params, toks, positions)
-            for i, kind in enumerate(self.kinds):
-                p = self.params["layers"][i]
-                with TraceAnnotation("serve.qkv"):
-                    q, k_new, v_new = self._qkv[kind](p, x, positions)
+            positions, kpos = jax.device_put(
+                (np.arange(s, e, dtype=np.int32), np.arange(e, dtype=np.int32)))
+            layers, hits = self.params["layers"], self._experts_hit
+            with TraceAnnotation("serve.embed"), TraceAnnotation("serve.qkv"):
+                x, q, k_new, v_new = self._prefill_head(
+                    self.params, toks, positions)
+            for i, (kind, next_kind) in enumerate(self._next_kinds):
                 self.cache.write_at(req.sid, i, k_new, v_new, s)
                 k_full, v_full = self.cache.gather_kv(req.sid, i, e)
                 with TraceAnnotation("serve.layer_rest"):
-                    x, hits = self._prefill_rest[kind](
-                        p, x, q, k_full, v_full, positions, kpos, hits)
+                    x, hits, *qkv = self._prefill_fold[kind, next_kind](
+                        layers[i], x, q, k_full, v_full, positions, kpos,
+                        hits, layers[i + 1] if next_kind else None)
+                if qkv:
+                    q, k_new, v_new = qkv
             self._count_experts(chunk, hits)
+            # the head and a fold a layer; step() counts the pool's programs
+            self._stats.programs += len(self.kinds) + 1
             req.prefill_pos = e
             self.cache.commit_prefill(req.sid, e)
             if self.tp_plan is not None:
@@ -571,7 +642,8 @@ class ServeEngine:
             if e == len(req.prompt):
                 with TraceAnnotation("serve.sample"):
                     req.generated.append(
-                        int(self._greedy_next(self.params, x)[0]))
+                        int(np.asarray(self._greedy_next(self.params, x))[0]))
+                self._stats.programs += 1
                 if req.first_token_time is None:
                     req.first_token_time = self.now()
                     req.first_token_wall = time.perf_counter()
@@ -614,32 +686,35 @@ class ServeEngine:
         return reqs
 
     def _decode_batch(self, reqs: List[Request]) -> None:
-        cfg, lay = self.cfg, self.layout
         B = len(reqs)
         with TraceAnnotation("serve.decode", batch=B):
             sids = [r.sid for r in reqs]
             pos = [int(self.cache.lengths[r.sid]) for r in reqs]
             tokens = np.asarray([[r.generated[-1]] for r in reqs], np.int32)
-            positions = np.asarray(pos, np.int32)[:, None]
+            positions = jax.device_put(np.asarray(pos, np.int32)[:, None])
             pt, ln = self.cache.batch_view(sids)
 
-            hits = self._experts_hit
-            with TraceAnnotation("serve.embed"):
-                x = self._embed(self.params, tokens, positions)
-            for i, kind in enumerate(self.kinds):
-                p = self.params["layers"][i]
-                with TraceAnnotation("serve.qkv"):
-                    q, k_new, v_new = self._qkv[kind](p, x, positions)
+            layers, hits = self.params["layers"], self._experts_hit
+            with TraceAnnotation("serve.embed"), TraceAnnotation("serve.qkv"):
+                x, q, k_new, v_new = self._decode_head(
+                    self.params, tokens, positions)
+            for i, (kind, next_kind) in enumerate(self._next_kinds):
                 self.cache.write_token(sids, i, k_new, v_new, pos)
                 window = self.window[kind]
                 with TraceAnnotation("serve.attention", window=window):
-                    qd = q.reshape(B, lay.n_q_eff, cfg.head_dim)
-                    o = paged_attention(qd, self.cache.k_pools[i],
-                                        self.cache.v_pools[i], pt, ln + 1,
+                    o = paged_attention(q, self.cache.k_pools[i],
+                                        self.cache.v_pools[i], pt, ln,
                                         window=window)
                 with TraceAnnotation("serve.layer_rest"):
-                    x, hits = self._decode_rest(p, x, o[:, None], hits)
+                    x, hits, *qkv = self._decode_fold[next_kind](
+                        layers[i], x, o, hits,
+                        layers[i + 1] if next_kind else None, positions)
+                if qkv:
+                    q, k_new, v_new = qkv
             self._count_experts(B, hits)
+            # the head, a kernel and a fold a layer, the sampler; step()
+            # counts the pool's programs
+            self._stats.programs += 2 * len(self.kinds) + 2
             with TraceAnnotation("serve.sample"):
                 nxt = np.asarray(self._greedy_next(self.params, x))
             self.cache.commit_token(sids, pos)
@@ -657,7 +732,8 @@ class ServeEngine:
     @property
     def stats(self) -> EngineStats:
         """The counters, with the device's count of experts hit read in."""
-        self._stats.experts_hit = int(self._experts_hit)
+        if self._experts_hit is not None:
+            self._stats.experts_hit = int(self._experts_hit)
         return self._stats
 
     def _count_experts(self, tokens: int, hits) -> None:
@@ -685,6 +761,7 @@ class ServeEngine:
         """One engine step: admit/resume, chunked prefill, prefetch, decode.
         Returns True while any request is in flight."""
         with TraceAnnotation("serve.step", step=self._steps):
+            pool0 = self.cache.kv_pool_calls
             if self.fault_plan is not None:
                 self._apply_faults()
             pre0 = self._stats.preempted
@@ -715,6 +792,7 @@ class ServeEngine:
             progress += self._stats.recovered_requests - rec0
             if self.um is not None:
                 self.um.sync()  # sync point: apply counter-driven delayed migrations
+            self._stats.programs += self.cache.kv_pool_calls - pool0
             self._steps += 1
             in_flight = self._in_flight()
         if in_flight and progress == 0:

@@ -374,6 +374,8 @@ class PagedKVCache:
 
     # ------------------------------------------------------------- views
     def batch_view(self, sids):
+        """A decode batch's page-table rows and the keys each sequence
+        attends, its new token's included (length + 1), on the device."""
         with TraceAnnotation("serve.kv_view"):
             return (jnp.asarray(self.page_table[sids]),
-                    jnp.asarray(self.lengths[sids]))
+                    jnp.asarray(self.lengths[sids] + 1))

@@ -71,9 +71,15 @@ def test_spans_nest_in_steps_and_count_layers(model, tmp_path, with_um):
     assert len(decodes) == eng.stats.decode_batches
     for outer in prefills + decodes:
         names = [s[0] for s in spans if inside(outer, s) and s is not outer]
-        for per_layer in ("serve.qkv", "serve.kv_write", "serve.layer_rest"):
+        for per_layer in ("serve.kv_write", "serve.layer_rest"):
             assert names.count(per_layer) == L, (outer, per_layer)
-        assert names.count("serve.embed") == 1
+        # one program embeds and projects layer 0's QKV; every later
+        # layer's QKV runs inside the layer before's serve.layer_rest
+        (embed,) = [s for s in spans if s[0] == "serve.embed"
+                    and inside(outer, s)]
+        (qkv,) = [s for s in spans if s[0] == "serve.qkv"
+                  and inside(outer, s)]
+        assert inside(embed, qkv)
         if outer[0] == "serve.prefill":
             assert names.count("serve.kv_gather") == L
         else:
@@ -96,24 +102,40 @@ def test_programs_have_names_of_their_own(model):
     eng = engine(model)
     x = jax.numpy.zeros((1, 4, cfg.d_model), jax.numpy.float32)
     pos = np.arange(4, dtype=np.int32)
+    toks = np.zeros((1, 4), np.int32)
     p = params["layers"][0]
     qkv, prefill_rest = eng._qkv["attention"], eng._prefill_rest["attention"]
     q, k, v = qkv(p, x, pos)
     kpos = np.arange(4, dtype=np.int32)
-    o = jax.numpy.zeros((1, 1, eng.layout.n_q_eff, cfg.head_dim))
-    hits = jax.numpy.int32(0)
+    o = jax.numpy.zeros((1, eng.layout.n_q_eff, cfg.head_dim))
     pools = eng.cache.k_pools[0], eng.cache.v_pools[0]
     pids, slots = np.ones(4, np.int32), pos
+    dpos = pos[:1, None]
     lowered = {
-        "embed": eng._embed.lower(params, np.zeros((1, 4), np.int32), pos),
+        "embed": eng._embed.lower(params, toks, pos),
         "layer_qkv": qkv.lower(p, x, pos),
         "prefill_layer_rest": prefill_rest.lower(
-            p, x, q, k[0], v[0], pos, kpos, hits),
-        "layer_rest": eng._decode_rest.lower(p, x[:, :1], o, hits),
+            p, x, q, k[0], v[0], pos, kpos, None),
+        "layer_rest": eng._decode_rest.lower(p, x[:, :1], o[:, None], None),
         "greedy_next": eng._greedy_next.lower(params, x),
         "kv_write": paged.kv_write.lower(*pools, pids, slots, k, v),
         "kv_gather": paged.kv_gather.lower(*pools, pids, slots),
+        # the programs the step dispatches, folded from those above
+        "embed_qkv": eng._decode_head.lower(params, toks[:, :1], dpos),
+        "layer_rest_qkv": eng._decode_fold["attention"].lower(
+            p, x[:, :1], o, None, p, dpos),
+        "prefill_layer_rest_qkv": eng._prefill_fold[
+            "attention", "attention"].lower(
+            p, x, q, k[0], v[0], pos, kpos, None, p),
     }
+    assert eng._decode_fold[None].lower(
+        p, x[:, :1], o, None, None, dpos).as_text().startswith(
+        "module @jit_layer_rest ")
+    assert eng._prefill_fold["attention", None].lower(
+        p, x, q, k[0], v[0], pos, kpos, None, None).as_text().startswith(
+        "module @jit_prefill_layer_rest ")
+    assert eng._prefill_head.lower(params, toks, pos).as_text().startswith(
+        "module @jit_embed_qkv ")
     for name, low in lowered.items():
         head = low.as_text().splitlines()[0]
         assert head.startswith(f"module @jit_{name} "), head

@@ -131,3 +131,52 @@ def test_dropless_experts_compile_to_grouped_matmuls(one_chip, T):
     # trace shows and bench/metrics/expert_ffn_roofline.py reads
     assert len(re.findall(r"%ragged-dot-none[.\d]* = \S+ custom-call\(",
                           hlo)) == 3
+
+
+def _spec_tree(tree, sharding):
+    return jax.tree_util.tree_map(
+        lambda s: _sds(s.shape, s.dtype, sharding), tree)
+
+
+# the serve engine's folded programs (a layer's rest and the next layer's
+# QKV in one program) at published widths in bf16: yi-6b's dense layer,
+# and mellum2's last sliding layer followed by its full one; a decode batch
+# of 16 and a 128-token prefill chunk over a 1,024-token prefix
+@pytest.mark.parametrize("name,kinds", [
+    ("yi-6b", ("attention", "attention")),
+    ("mellum2-12b-a2.5b", ("local", "attention"))])
+def test_folded_serve_programs_compile(one_chip, name, kinds):
+    import dataclasses
+
+    from repro.configs import get_config
+    from repro.models import init_params_specs
+    from repro.serve import ServeEngine
+
+    cfg = dataclasses.replace(get_config(name), num_layers=4)
+    i = 2  # layer 2 of kinds[0], followed by layer 3 of kinds[1]
+    assert cfg.layer_kinds()[i:i + 2] == list(kinds)
+    params = _spec_tree(init_params_specs(cfg), one_chip)
+    eng = ServeEngine(cfg, params, max_seqs=1, max_len=64, page_size=64)
+    p, p_next = params["layers"][i], params["layers"][i + 1]
+    d, H, D = cfg.d_model, eng.layout.n_q_eff, cfg.head_dim
+    N = eng.layout.n_kv_eff
+    bf16 = jnp.bfloat16
+    B, S, E = 16, 128, 1024
+    i32 = lambda *shape: _sds(shape, jnp.int32, one_chip)
+    decode = eng._decode_fold[kinds[1]].lower(
+        p, _sds((B, 1, d), bf16, one_chip), _sds((B, H, D), bf16, one_chip),
+        None if not cfg.is_moe else i32(), p_next, i32(B, 1))
+    x = _sds((1, S, d), bf16, one_chip)
+    q = jax.eval_shape(eng._qkv[kinds[0]], p, x, i32(S))[0]
+    prefill = eng._prefill_fold[kinds].lower(
+        p, x, _sds(q.shape, q.dtype, one_chip),
+        _sds((E, N, D), jnp.float32, one_chip),
+        _sds((E, N, D), jnp.float32, one_chip), i32(S), i32(E),
+        None if not cfg.is_moe else i32(), p_next)
+    for low, name in ((decode, "layer_rest_qkv"),
+                      (prefill, "prefill_layer_rest_qkv")):
+        assert low.as_text().startswith(f"module @jit_{name} ")
+        hlo = low.compile().as_text()
+        # the expert matmuls stay grouped matmuls inside the program
+        n = len(re.findall(r"%ragged-dot-none[.\d]* = \S+ custom-call\(", hlo))
+        assert n == (3 if cfg.is_moe else 0), n
